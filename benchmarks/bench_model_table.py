@@ -126,22 +126,15 @@ def time_chunk_rows() -> list[str]:
         c = dataclasses.replace(cfg, time_chunk=tc)
         acct = lif_residual_accounting(c, batch=2)
         stored = acct["tiled_bytes"]
-        try:
-            # AOT-compile once and reuse the executable for the grads (a
-            # plain grad_fn(...) call would compile a second time — the
-            # jit call cache does not see manual lower().compile()).
-            compiled = grad_fn.lower(params, state, imgs, labels,
-                                     c).compile()
-            temp = getattr(compiled.memory_analysis(),
-                           "temp_size_in_bytes", None)
-            (_, _), grads = compiled(params, state, imgs, labels)
-        except Exception:
-            temp = None
-            (_, _), grads = grad_fn(params, state, imgs, labels, c)
+        # AOT-compile once and reuse the executable for the grads (a plain
+        # grad_fn(...) call would compile a second time — the jit call
+        # cache does not see manual lower().compile()).
+        compiled = grad_fn.lower(params, state, imgs, labels, c).compile()
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        (_, _), grads = compiled(params, state, imgs, labels)
         diff = max(float(jnp.max(jnp.abs(a - b))) for a, b in
                    zip(jax.tree.leaves(base_grads), jax.tree.leaves(grads)))
-        lines.append(f"{tc},{stored},{temp if temp is not None else 'n/a'},"
-                     f"{diff:.2e}")
+        lines.append(f"{tc},{stored},{temp},{diff:.2e}")
     return lines
 
 

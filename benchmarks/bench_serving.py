@@ -150,7 +150,7 @@ def run(smoke: bool = False, *, slots: int | None = None,
         f"p99_latency_steps,{float(p99):.1f}",
         f"p50_latency_s,{float(p50) * sec_per_step:.4f}",
         f"p99_latency_s,{float(p99) * sec_per_step:.4f}",
-        f"decode_traces,{engine.trace_count() or 1}",
+        f"decode_traces,{engine.trace_count()}",
     ]
 
 

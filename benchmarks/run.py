@@ -85,6 +85,9 @@ def main() -> None:
                     help="also write section->metric->value JSON to PATH")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (bench_autotune, bench_comparison,
                             bench_dataflows, bench_energy_breakdown,
                             bench_kernels, bench_model_table, bench_serving)

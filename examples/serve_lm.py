@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_config, reduced
+from repro.launch.cache import enable_compile_cache
 from repro.launch.train import train
 from repro.serving.engine import Request, ServingEngine
 from repro.train.data import DataConfig, SyntheticLM
@@ -25,6 +26,7 @@ def main() -> None:
     ap.add_argument("--train-steps", type=int, default=400)
     ap.add_argument("--requests", type=int, default=6)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced(get_config("qwen3-0.6b")).replace(vocab_size=64)
     params, history = train(cfg, steps=args.train_steps, global_batch=16,

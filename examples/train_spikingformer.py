@@ -22,6 +22,7 @@ import numpy as np
 from repro.configs.spikingformer import get_spikingformer_config
 from repro.core.policy import list_named_policies, named_policy
 from repro.core.spikingformer import init_spikingformer
+from repro.launch.cache import enable_compile_cache
 from repro.train.checkpoint import save_checkpoint
 from repro.train.data import SyntheticVision, VisionDataConfig
 from repro.train.loop import make_spikingformer_train_step
@@ -46,6 +47,7 @@ def main() -> None:
     ap.add_argument("--spike-mm", action="store_true",
                     help="deprecated: use --policy pallas-full")
     args = ap.parse_args()
+    enable_compile_cache()
 
     policy = named_policy(args.policy)
     if args.spike_mm:

@@ -40,6 +40,7 @@ from __future__ import annotations
 from typing import Iterable, Mapping, Sequence
 
 from repro.analysis.report import Finding, error, info, warning
+from repro.core.spikingformer import fused_site_geometries
 
 __all__ = ["audit_breaker", "audit_mesh_plans", "audit_serving_caches",
            "audit_spikingformer_plans", "audit_tuned_table",
@@ -53,26 +54,6 @@ _SKIP_CACHE_FAMILIES = {"audio"}
 # ---------------------------------------------------------------------------
 # Plan audit: presets x policies
 # ---------------------------------------------------------------------------
-
-def fused_site_geometries(cfg, batch: int) -> dict[str, tuple]:
-    """``site -> (t, m, c, k)`` matmul geometry for every fused-epilogue
-    candidate site of a Spikingformer config, at global batch ``batch`` —
-    the inputs :func:`repro.kernels.neuron_layer.train_arm_vmem_bytes`
-    prices. Conv stages use their im2col geometry (rows = batch x out-pixel
-    count, contraction = 9 x c_in); the Q/K/V projections share one site
-    and one geometry."""
-    t, n, d = cfg.time_steps, cfg.num_tokens, cfg.d_model
-    geoms: dict[str, tuple] = {}
-    h = cfg.image_size
-    for i, (c_in, c_out) in enumerate(cfg.tokenizer_stage_channels()):
-        h //= 2
-        geoms[f"tokenizer.conv.{i}"] = (t, batch * h * h, 9 * c_in, c_out)
-    geoms["pssa.qkv"] = (t, batch * n, d, d)
-    geoms["pssa.proj"] = (t, batch * n, d, d)
-    geoms["smlp.a"] = (t, batch * n, d, cfg.d_ff)
-    geoms["smlp.b"] = (t, batch * n, cfg.d_ff, d)
-    return geoms
-
 
 def audit_spikingformer_plans(presets: Sequence[str] | None = None,
                               policies: Mapping[str, object] | None = None,

@@ -190,10 +190,10 @@ def _check_spec(rec: PallasCallRecord, spec, shape: tuple[int, ...],
                              f"block dim {b} exceeds operand dim {s} at "
                              f"axis {d}"))
     # TPU sublane/lane alignment ((8, 128) fp32 min tile): a block dim must
-    # be tile-aligned or cover the whole axis. The packed uint8 contraction
-    # axis is exempt from the lane rule — its alignment contract is the %8
-    # pack granularity, enforced by the pack/unpack asserts.
-    if len(block) >= 2 and jnp.dtype(dtype) != jnp.uint8:
+    # be tile-aligned or cover the whole axis — the packed uint8 operand
+    # too (its contraction tiles are whole 128-byte pack groups, or all of
+    # C; see ``spike_matmul.contraction_block``).
+    if len(block) >= 2:
         b_last, s_last = block[-1], shape[-1]
         if b_last % 128 != 0 and b_last != s_last:
             out.append(warning(

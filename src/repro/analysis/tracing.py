@@ -19,10 +19,6 @@ Two mechanisms, used automatically:
   (``fn._cache_size()``) before/after the block;
 * with no callables, a process-global compile counter hooked off jax's
   compilation log records, covering jits created *inside* the block.
-
-Both degrade gracefully: when a jax version exposes neither hook the guard
-becomes a no-op rather than a false failure (``trace_count`` returns
-``None``; the engine reports that as "unknown", and tests skip).
 """
 from __future__ import annotations
 
@@ -32,19 +28,14 @@ from typing import Any, Callable, Iterator
 
 __all__ = ["assert_trace_count", "compile_counter", "trace_count"]
 
-#: Logger jax emits per-compilation records on (stable across 0.4.x; the
-#: guard no-ops if the messages move).
+#: Logger jax emits per-compilation records on, at DEBUG.
 _DISPATCH_LOGGER = "jax._src.dispatch"
 _COMPILE_MARKER = "Finished XLA compilation"
 
 
-def trace_count(fn: Callable[..., Any]) -> int | None:
-    """Number of traces a jitted callable has compiled so far, or ``None``
-    when this jax version does not expose the compile-cache hook."""
-    try:
-        return fn._cache_size()
-    except AttributeError:
-        return None
+def trace_count(fn: Callable[..., Any]) -> int:
+    """Number of traces a jitted callable has compiled so far."""
+    return fn._cache_size()
 
 
 class _CompileCountHandler(logging.Handler):
@@ -61,7 +52,7 @@ class _CompileCountHandler(logging.Handler):
 def compile_counter() -> Iterator[Callable[[], int]]:
     """Context manager yielding a zero-argument callable that returns the
     number of XLA compilations since the block was entered (process-global,
-    any jit). Counts 0 forever if the log hook is unavailable."""
+    any jit)."""
     log = logging.getLogger(_DISPATCH_LOGGER)
     handler = _CompileCountHandler()
     prev_level = log.level
@@ -92,10 +83,7 @@ def assert_trace_count(n: int, *fns: Callable[..., Any],
         before = [trace_count(f) for f in fns]
         yield
         for f, b in zip(fns, before):
-            a = trace_count(f)
-            if b is None or a is None:
-                continue   # hook unavailable: no-op, never a false failure
-            _check(a - b, n, exact, getattr(f, "__name__", repr(f)))
+            _check(trace_count(f) - b, n, exact, getattr(f, "__name__", repr(f)))
     else:
         with compile_counter() as count:
             yield

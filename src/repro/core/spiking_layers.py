@@ -203,29 +203,39 @@ def _linear_bn_spike_mm(params, state, x, train, policy, site):
     return y, {"bn": bn_s}
 
 
-def _train_arm_exceeds_vmem(x, k_out, packed, policy, site) -> bool:
-    """Capacity guard for the train-mode megakernel on real hardware: its
+def train_arm_vmem_demotion(t: int, m: int, c: int, k: int, packed: bool,
+                            policy: ExecutionPolicy) -> str | None:
+    """Why the train-mode megakernel cannot hold a ``(T, M, C) @ (C, K)``
+    site on the compiling backend, or None where it fits. Its
     BN-statistics constraint pins all T*M rows to one program, so at large
-    M the accumulator outgrows VMEM where the M-tiled pipeline still fits.
-    ``packed`` must be the arm the caller will actually run (a dense-arm x
-    tile is 32x a packed one). Interpret mode (every CPU/CI run) has no
-    such limit and always stays fused; on a compiling backend the demotion
-    is logged (INFO — a planned capacity decision, like the structural
-    ones)."""
+    M its tiles outgrow VMEM where the M-tiled pipeline still fits.
+    ``packed`` must be the arm the caller will actually run. Interpret
+    mode (every CPU/CI run) has no such limit. The execution plan
+    (``SpikingFormerConfig.execution_plan(batch=...)``) and the per-call
+    guard both decide through here, so they cannot disagree."""
     from repro.core.backend import resolve_interpret
     from repro.kernels import neuron_layer
 
     if resolve_interpret(policy.interpret):
-        return False
+        return None
+    est = neuron_layer.train_arm_vmem_bytes(t, m, c, k, packed=packed)
+    budget = neuron_layer.TRAIN_ARM_VMEM_BUDGET
+    if est <= budget:
+        return None
+    return (f"train-arm VMEM estimate {est / 2**20:.1f} MiB > "
+            f"{budget / 2**20:.1f} MiB (all T*M rows per program)")
+
+
+def _train_arm_exceeds_vmem(x, k_out, packed, policy, site) -> bool:
+    """Per-call capacity guard (see :func:`train_arm_vmem_demotion`); the
+    demotion is logged at INFO — a planned capacity decision, which the
+    plan reports too when it is given the batch."""
     t, m, c = x.shape[0], math.prod(x.shape[1:-1]), x.shape[-1]
-    est = neuron_layer.train_arm_vmem_bytes(t, m, c, k_out, packed=packed)
-    if est <= neuron_layer.TRAIN_ARM_VMEM_BUDGET:
+    reason = train_arm_vmem_demotion(t, m, c, k_out, packed, policy)
+    if reason is None:
         return False
-    runtime_fallback(
-        site, "fused_epilogue",
-        f"train-arm VMEM estimate {est >> 20} MiB > "
-        f"{neuron_layer.TRAIN_ARM_VMEM_BUDGET >> 20} MiB "
-        f"(all T*M rows per program) -> pipeline", expected=True)
+    runtime_fallback(site, "fused_epilogue", reason + " -> pipeline",
+                     expected=True)
     return True
 
 
@@ -416,8 +426,10 @@ def _attn_qk_packed(q, k, policy, site):
     t, b, h, n, dh = q.shape
     m = k.shape[3]
     if dh % 8 != 0:
+        # Architectural (d_model / n_heads), so the plan marks it expected.
         runtime_fallback(site, "pallas_packed",
-                         f"head dim {dh} % 8 != 0 -> jnp einsum")
+                         f"head dim {dh} % 8 != 0 -> jnp einsum",
+                         expected=True)
         return _attn_qk_jnp(q, k, policy, site)
     from repro.kernels import ops
 
@@ -448,8 +460,10 @@ def _attn_av_packed(attn, v, policy, site):
     t, b, h, n, m = attn.shape
     dh = v.shape[-1]
     if m % 8 != 0:
+        # Architectural (patch_grid^2), so the plan marks it expected.
         runtime_fallback(site, "pallas_packed",
-                         f"token count {m} % 8 != 0 -> jnp einsum")
+                         f"token count {m} % 8 != 0 -> jnp einsum",
+                         expected=True)
         return _attn_av_jnp(attn, v, policy, site)
     from repro.kernels import ops
 

@@ -19,8 +19,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.lif import LIFConfig, lif_scan
-from repro.core.policy import (ExecutionPolicy, apply_legacy_exec_flags,
-                               get_kernel, plan_sites, policy_from_flags,
+from repro.core.policy import (ExecutionPolicy, FUSED_EPILOGUE_IMPLS,
+                               apply_legacy_exec_flags,
+                               fused_epilogue_fallback, get_kernel,
+                               plan_sites, policy_from_flags,
                                register_kernel, register_site_table,
                                runtime_fallback, warn_deprecated_flags)
 from repro.core.spiking_layers import (ACT_SPECS, BlockConfig, _bn_pallas,
@@ -178,10 +180,17 @@ class SpikingFormerConfig:
             ("smlp.b", "linear_bn", self.d_ff, True, False),
         )
 
-    def execution_plan(self):
+    def execution_plan(self, batch: int | None = None):
         """Resolve the policy once against this model's shapes: one
         :class:`~repro.core.policy.SiteDecision` per site, with packing
         fallbacks decided here rather than silently per call.
+
+        Given the global ``batch`` of a train step, fused-epilogue sites
+        whose train-arm megakernel cannot hold the site's rows in VMEM on
+        the compiling backend are planned onto their pipeline arm (the
+        same decision the per-call guard makes, through
+        :func:`repro.core.spiking_layers.train_arm_vmem_demotion`); eval
+        keeps the single launch, which tiles M.
 
         Stages running a fused conv impl fold their BN into the
         Conv->BN->LIF pipeline (RTFormer-style re-parameterization in
@@ -201,6 +210,8 @@ class SpikingFormerConfig:
         rows[:] = [dataclasses.replace(r, expected=True)
                    if r.op in ("attn_qk", "attn_av") and r.note else r
                    for r in rows]
+        if batch is not None:
+            rows[:] = [self._plan_train_capacity(r, batch) for r in rows]
         conv_rows = [r for r in rows if r.op == "conv"]
 
         def annotate(site, subset, what):
@@ -223,18 +234,41 @@ class SpikingFormerConfig:
                  "absorbed into the single-launch neuron-layer megakernel")
         return rows
 
-    def describe_execution(self, mesh=None) -> str:
+    def _plan_train_capacity(self, row, batch: int):
+        """``row``, moved to its pipeline arm if the train-mode megakernel
+        cannot hold the site at ``batch`` (see :meth:`execution_plan`)."""
+        from repro.core.spiking_layers import train_arm_vmem_demotion
+
+        if row.effective not in FUSED_EPILOGUE_IMPLS:
+            return row
+        packed = "dense arm" not in row.note
+        reason = train_arm_vmem_demotion(
+            *fused_site_geometries(self, batch)[row.site], packed,
+            self.policy)
+        if reason is None:
+            return row
+        pipeline = (fused_epilogue_fallback(row.op, row.effective)
+                    if row.op == "linear_bn"
+                    else "pallas_packed" if packed else "pallas")
+        note = (f"{reason} at batch {batch} -> {pipeline} in train "
+                f"(eval keeps the single launch)")
+        return dataclasses.replace(
+            row, effective=pipeline, expected=True,
+            note="; ".join(n for n in (row.note, note) if n))
+
+    def describe_execution(self, mesh=None, batch: int | None = None) -> str:
         """The per-site dispatch table (printed by bench_model_table),
         followed by the active tuned-block table's entries for this model's
         sites (``repro.tune`` — which block sizes/arms kernel dispatch will
         pick up at trace time), then the sharding plan: the activation
         partition specs the model constrains to, and — when ``mesh`` is
         given — the effective parameter shardings (post sanitize + FSDP)
-        on that mesh."""
+        on that mesh. ``batch`` plans the train step's capacity demotions
+        (see :meth:`execution_plan`)."""
         from repro.core.policy import describe_breaker
         from repro.tune.table import describe_tuned
 
-        rows = self.execution_plan()
+        rows = self.execution_plan(batch)
         out = self.policy.describe(rows=rows)
         tuned = describe_tuned([r.site for r in rows])
         breaker = describe_breaker()
@@ -274,6 +308,27 @@ class SpikingFormerConfig:
                   for ci, co in self.tokenizer_stage_channels())
         head = self.d_model * self.num_classes + self.num_classes
         return self.num_layers * per_block + tok + head
+
+
+def fused_site_geometries(cfg: SpikingFormerConfig, batch: int
+                          ) -> dict[str, tuple]:
+    """``site -> (t, m, c, k)`` matmul geometry for every fused-epilogue
+    candidate site of a Spikingformer config, at global batch ``batch`` —
+    the inputs :func:`repro.kernels.neuron_layer.train_arm_vmem_bytes`
+    prices. Conv stages use their im2col geometry (rows = batch x out-pixel
+    count, contraction = 9 x c_in); the Q/K/V projections share one site
+    and one geometry."""
+    t, n, d = cfg.time_steps, cfg.num_tokens, cfg.d_model
+    geoms: dict[str, tuple] = {}
+    h = cfg.image_size
+    for i, (c_in, c_out) in enumerate(cfg.tokenizer_stage_channels()):
+        h //= 2
+        geoms[f"tokenizer.conv.{i}"] = (t, batch * h * h, 9 * c_in, c_out)
+    geoms["pssa.qkv"] = (t, batch * n, d, d)
+    geoms["pssa.proj"] = (t, batch * n, d, d)
+    geoms["smlp.a"] = (t, batch * n, d, cfg.d_ff)
+    geoms["smlp.b"] = (t, batch * n, cfg.d_ff, d)
+    return geoms
 
 
 # ---------------------------------------------------------------------------

@@ -31,9 +31,9 @@ in.
 Layouts: ``x`` is time-major (T, M, C) with M = B*N (or B*Ho*Wo) rows
 folded; ``w`` is (C, K). Train mode tiles (K, C) and owns all T*M rows per
 program (the BN-statistics constraint); eval mode additionally tiles M.
-VMEM budget = the fp32 (T, M|bm, bk) accumulator plus the x/w tiles — the
-defaults keep the smoke/bench shapes well under the ~16 MB v5e budget; a
-real-TPU soak should tune ``block_*`` per site.
+The train arm's VMEM grows with T*M (see :func:`train_arm_vmem_bytes`);
+where it outgrows the compiler's limit the caller plans the M-tiled
+pipeline instead.
 """
 from __future__ import annotations
 
@@ -46,14 +46,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.backend import resolve_interpret
-from repro.kernels.spike_matmul import spike_pack, spike_unpack
+from repro.kernels.spike_matmul import (contraction_block, pack_group,
+                                       spike_pack, spike_unpack)
 
 
-def _accumulate(x_ref, w_ref, acc_ref, *, packed, time_steps):
-    """acc[t] += x_t @ w for every unrolled time step (one (c, k) block)."""
+def _accumulate(x_ref, w_ref, acc_ref, *, group_bytes, time_steps):
+    """acc[t] += x_t @ w for every unrolled time step (one (c, k) block);
+    ``group_bytes`` is the pack group width of a packed x, None if dense."""
     w = w_ref[...]
     for t in range(time_steps):
-        xt = spike_unpack(x_ref[t], dtype=w.dtype) if packed else x_ref[t]
+        xt = (spike_unpack(x_ref[t], dtype=w.dtype, group_bytes=group_bytes)
+              if group_bytes else x_ref[t])
         acc_ref[t] += jnp.dot(xt, w, preferred_element_type=jnp.float32)
 
 
@@ -68,8 +71,8 @@ def _soma(acc_ref, s_ref, y_of_t, *, alpha, th_fire, time_steps):
 
 
 def _nl_train_kernel(x_ref, w_ref, gamma_ref, beta_ref, s_ref, mu_ref,
-                     var_ref, acc_ref, *, n_cb, packed, alpha, th_fire, eps,
-                     time_steps, m_rows):
+                     var_ref, acc_ref, *, n_cb, group_bytes, alpha, th_fire,
+                     eps, time_steps, m_rows):
     """Grid (K/bk, C/bc): accumulate over C, then BN-stats + SOMA epilogue.
 
     Each program owns all T*M rows of its feature block, so the batch
@@ -83,7 +86,8 @@ def _nl_train_kernel(x_ref, w_ref, gamma_ref, beta_ref, s_ref, mu_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _accumulate(x_ref, w_ref, acc_ref, packed=packed, time_steps=time_steps)
+    _accumulate(x_ref, w_ref, acc_ref, group_bytes=group_bytes,
+                time_steps=time_steps)
 
     @pl.when(cb == n_cb - 1)
     def _epilogue():
@@ -103,8 +107,8 @@ def _nl_train_kernel(x_ref, w_ref, gamma_ref, beta_ref, s_ref, mu_ref,
         var_ref[...] = var
 
 
-def _nl_eval_kernel(x_ref, w_ref, b_ref, s_ref, acc_ref, *, n_cb, packed,
-                    alpha, th_fire, time_steps):
+def _nl_eval_kernel(x_ref, w_ref, b_ref, s_ref, acc_ref, *, n_cb,
+                    group_bytes, alpha, th_fire, time_steps):
     """Grid (M/bm, K/bk, C/bc): BN pre-folded into (w, bias) by the caller
     (fixed running statistics), so the epilogue is bias + SOMA."""
     cb = pl.program_id(2)
@@ -113,7 +117,8 @@ def _nl_eval_kernel(x_ref, w_ref, b_ref, s_ref, acc_ref, *, n_cb, packed,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _accumulate(x_ref, w_ref, acc_ref, packed=packed, time_steps=time_steps)
+    _accumulate(x_ref, w_ref, acc_ref, group_bytes=group_bytes,
+                time_steps=time_steps)
 
     @pl.when(cb == n_cb - 1)
     def _epilogue():
@@ -122,39 +127,45 @@ def _nl_eval_kernel(x_ref, w_ref, b_ref, s_ref, acc_ref, *, n_cb, packed,
               alpha=alpha, th_fire=th_fire, time_steps=time_steps)
 
 
-#: VMEM the train-arm megakernel may assume per program before the caller
-#: should prefer the M-tiled pipeline on real hardware (the ~16 MB v5e
-#: budget minus headroom for double buffering). Interpret mode has no such
-#: limit, so the guard only matters when actually lowering to Mosaic.
-TRAIN_ARM_VMEM_BUDGET: int = 12 * 2 ** 20
+#: The v5e compiler's default scoped-VMEM limit per kernel, which
+#: :func:`train_arm_vmem_bytes` is checked against. Interpret mode has no
+#: such limit, so the guard only matters when lowering to Mosaic.
+TRAIN_ARM_VMEM_BUDGET: int = 16 * 2 ** 20
+
+
+def _lanes(n: int) -> int:
+    """``n`` rounded up to whole 128-lane vregs (VMEM pads the minor dim)."""
+    return -(-n // 128) * 128
 
 
 def train_arm_vmem_bytes(t: int, m: int, c: int, k: int, packed: bool, *,
                          block_k: int = 256, block_c: int = 256) -> int:
-    """Estimated per-program VMEM of the train-mode megakernel: the fp32
-    accumulator + spike output (each (T, M, bk) — the BN-statistics
-    constraint pins all T*M rows to one program) plus the x/w tiles.
-    Callers compare against :data:`TRAIN_ARM_VMEM_BUDGET` to decide, per
-    call and logged, whether the single-launch train arm fits or the
-    M-tiled pipeline should run instead."""
+    """Estimated per-program VMEM of the train-mode megakernel: per row of
+    the T*M rows one program owns (the BN-statistics constraint), the fp32
+    accumulator and the double-buffered spike output (each T x bk), the
+    double-buffered x tile and, packed, the int32 words and fp32 spikes
+    one x_t unpacks to; plus the
+    double-buffered (bc, bk) weight tile. Minor dims pad to 128 lanes.
+
+    Checked against Mosaic for v5e at 23 ``spikingformer-8-512`` site
+    geometries (T=4) and one ``spikingformer-smoke`` geometry (T=2, M=4096,
+    C=K=64): every one estimated under :data:`TRAIN_ARM_VMEM_BUDGET`
+    compiled, and every one that failed was estimated over it; two that
+    compiled were estimated over it (the estimate errs towards the
+    pipeline). Callers compare against the budget
+    to decide, in the plan and per call, whether the single-launch train
+    arm fits or the M-tiled pipeline runs instead."""
     bk = min(block_k, k)
-    bc = _contraction_block(block_c, c, packed)
-    x_tile = t * m * (bc // 8 if packed else bc * 4)
-    return 2 * t * m * bk * 4 + x_tile + bc * bk * 4
+    bc = contraction_block(block_c, c, packed)
+    x_row = t * (_lanes(bc // 8) if packed else _lanes(bc) * 4)
+    # Unpacking widens the bytes to int32 words, then to fp32 spikes.
+    unpacked_row = (_lanes(bc // 8) + _lanes(bc)) * 4 if packed else 0
+    per_row = 3 * t * _lanes(bk) * 4 + 2 * x_row + unpacked_row
+    return m * per_row + 2 * bc * _lanes(bk) * 4
 
 
-def _contraction_block(block_c: int, c: int, packed: bool) -> int:
-    """Largest divisor of C <= block_c (the C axis is accumulated, so a
-    ragged final block would fold BlockSpec padding into every output tile);
-    packed arms additionally need the byte-packing granularity. A true
-    divisor search, not gcd — gcd(min(block_c, c), c) collapses to tiny
-    blocks on awkward C (e.g. 8 for C = 520), starving the MXU."""
-    if packed:
-        assert c % 8 == 0, f"packed contraction dim {c} must be * of 8"
-    for bc in range(min(block_c, c), 0, -1):
-        if c % bc == 0 and (not packed or bc % 8 == 0):
-            return bc
-    return c  # unreachable: bc = 1 (or 8 when packed) always divides C
+def _group_bytes(c: int, packed: bool) -> int | None:
+    return pack_group(c) // 8 if packed else None
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -178,13 +189,14 @@ def neuron_layer_train(x: jax.Array, w: jax.Array, gamma: jax.Array,
     cw, k = w.shape
     assert cw == c, f"weight contraction {cw} != input {c}"
     bk = min(block_k, k)
-    bc = _contraction_block(block_c, c, packed)
+    bc = contraction_block(block_c, c, packed)
     xin = spike_pack(x) if packed else x
     xspec = pl.BlockSpec((t, m, bc // 8 if packed else bc),
                          lambda j, cb: (0, 0, cb))
     vec = pl.BlockSpec((1, bk), lambda j, cb: (0, j))
     grid = (pl.cdiv(k, bk), pl.cdiv(c, bc))
-    kernel = functools.partial(_nl_train_kernel, n_cb=grid[1], packed=packed,
+    kernel = functools.partial(_nl_train_kernel, n_cb=grid[1],
+                               group_bytes=_group_bytes(c, packed),
                                alpha=alpha, th_fire=th_fire, eps=eps,
                                time_steps=t, m_rows=m)
     return pl.pallas_call(
@@ -218,12 +230,13 @@ def neuron_layer_eval(x: jax.Array, w: jax.Array, bias: jax.Array, *,
     cw, k = w.shape
     assert cw == c, f"weight contraction {cw} != input {c}"
     bm, bk = min(block_m, m), min(block_k, k)
-    bc = _contraction_block(block_c, c, packed)
+    bc = contraction_block(block_c, c, packed)
     xin = spike_pack(x) if packed else x
     xspec = pl.BlockSpec((t, bm, bc // 8 if packed else bc),
                          lambda i, j, cb: (0, i, cb))
     grid = (pl.cdiv(m, bm), pl.cdiv(k, bk), pl.cdiv(c, bc))
-    kernel = functools.partial(_nl_eval_kernel, n_cb=grid[2], packed=packed,
+    kernel = functools.partial(_nl_eval_kernel, n_cb=grid[2],
+                               group_bytes=_group_bytes(c, packed),
                                alpha=alpha, th_fire=th_fire, time_steps=t)
     return pl.pallas_call(
         kernel, grid=grid,

@@ -14,25 +14,16 @@ from jax.sharding import PartitionSpec as P
 from repro.models.common import spec_is_leaf
 
 
-def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: axis_types landed after 0.4.x."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices)
 
 
 def use_mesh(mesh):
     """Context manager activating ``mesh`` for sharding-constraint
-    resolution: ``jax.set_mesh`` on current jax, the legacy ``with mesh:``
-    context on releases that predate it (a ``Mesh`` is itself a context
-    manager there)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    resolution (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -41,9 +32,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _make_mesh(shape, axes)
 
 
-def make_test_mesh(data: int = 1, model: int = 1):
-    """Small mesh for CPU tests (1 device => (1, 1))."""
-    return _make_mesh((data, model), ("data", "model"))
+def make_test_mesh(data: int = 1, model: int = 1, devices=None):
+    """Small (data, model) mesh: over all devices, or over ``devices``
+    (1 device => (1, 1))."""
+    return _make_mesh((data, model), ("data", "model"), devices)
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

@@ -24,6 +24,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.chaos import inject as chaos_inject
 from repro.configs.registry import get_config, reduced
+from repro.core.policy import log_fallbacks
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import (apply_fsdp, batch_axes, make_test_mesh,
                                sanitize_specs, use_mesh)
 from repro.models.common import spec_is_leaf, split_tree
@@ -152,6 +154,9 @@ def train_vision(cfg, *, steps: int, global_batch: int,
     ``place_batch``, checkpointing (params + BN state + optimizer) with
     elastic restore."""
     mesh = mesh or make_test_mesh(jax.device_count(), 1)
+    # The plan at this batch: capacity demotions are decided (and logged)
+    # here, from shape, before anything compiles.
+    log_fallbacks(cfg.execution_plan(global_batch))
     opt_cfg = OptimizerConfig(lr=lr, total_steps=steps, weight_decay=0.01,
                               warmup_steps=max(steps // 20, 5))
     params, state, opt_state, (p_specs, s_specs) = build_spikingformer_state(
@@ -324,6 +329,7 @@ def main() -> None:
                          "JSON; also honored via $CHAOS_SCHEDULE). See "
                          "docs/RESILIENCE.md")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.chaos_schedule:
         from repro.chaos import FaultSchedule, activate
         import os as _os

@@ -63,25 +63,10 @@ def stack_layer_trees(augs: list[Any]) -> Any:
 
 
 def _ambient_mesh():
-    """The mesh activated for sharding-constraint resolution, or None.
-
-    Current jax: ``jax.set_mesh`` -> ``get_abstract_mesh``. Older releases
-    (pre ``set_mesh``): the legacy ``with mesh:`` context, visible through
-    ``thread_resources.env.physical_mesh``."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()  # type: ignore[attr-defined]
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:  # e2a: ignore[E2A006] - probe: fall through to legacy
-        pass
-    try:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:  # e2a: ignore[E2A006] - probe: no mesh is a valid state
-        pass
-    return None
+    """The mesh activated by ``jax.set_mesh`` for sharding-constraint
+    resolution, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def shard(x: jax.Array, *spec) -> jax.Array:
@@ -90,12 +75,9 @@ def shard(x: jax.Array, *spec) -> jax.Array:
     Axis names absent from the context mesh are dropped from the spec, as
     are axes whose dim does not divide evenly (uneven GSPMD shardings
     round-trip poorly)."""
-    try:
-        mesh = _ambient_mesh()
-        names = set(mesh.axis_names) if mesh is not None else set()
-        sizes = dict(zip(mesh.axis_names, mesh.axis_sizes)) if names else {}
-    except Exception:
-        names, sizes = set(), {}
+    mesh = _ambient_mesh()
+    names = set(mesh.axis_names) if mesh is not None else set()
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes)) if names else {}
 
     def keep(ax, dim):
         if ax is None:

@@ -249,10 +249,9 @@ class ServingEngine:
         self.flush_resets()
         return cache_slot_state(self.cache, slot, self.cfg)
 
-    def trace_count(self) -> int | None:
+    def trace_count(self) -> int:
         """Number of traces the fused step has compiled (the single-trace
-        contract says this is 1); None when jax does not expose the hook.
-        Delegates to :func:`repro.analysis.tracing.trace_count`, the same
+        contract says this is 1). Delegates to :func:`repro.analysis.tracing.trace_count`, the same
         guard the trace-count tests pin ``make_train_step`` with."""
         return trace_count(self._step)
 

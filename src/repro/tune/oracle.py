@@ -117,11 +117,11 @@ def _pipeline_extra_cycles(mm: MMOp, arr: ArrayConfig) -> float:
 
 
 def _snap_bc(bc: int, c: int, packed: bool) -> int:
-    """Snap a block_c candidate the way the kernels do (divisor of C, %8
-    when packed) so the oracle scores what would actually run."""
-    from repro.kernels.neuron_layer import _contraction_block
+    """Snap a block_c candidate the way the kernels do (a TPU-tileable
+    divisor of C) so the oracle scores what would actually run."""
+    from repro.kernels.spike_matmul import contraction_block
 
-    return _contraction_block(bc, c, packed)
+    return contraction_block(bc, c, packed)
 
 
 def oracle_rank(wl: SiteWorkload, arr: ArrayConfig | None = None,

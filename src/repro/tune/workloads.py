@@ -93,7 +93,7 @@ def site_workloads(cfg, batch: int = 1,
     (see :func:`repro.tune.sparsity.measure_sparsity`); missing sites get
     the paper default for spike operands and 0.0 for dense ones.
     """
-    from repro.analysis.audit import fused_site_geometries
+    from repro.core.spikingformer import fused_site_geometries
 
     geoms = fused_site_geometries(cfg, batch)
     specs = _spec_map(cfg)
@@ -233,7 +233,7 @@ def kernel_shape_cases(cfg, batch: int = 1) -> list[KernelShapeCase]:
     backward kernels differ) and the full launch layout instead of the
     energy-model op counts.
     """
-    from repro.analysis.audit import fused_site_geometries
+    from repro.core.spikingformer import fused_site_geometries
 
     geoms = fused_site_geometries(cfg, batch)
     specs = _spec_map(cfg)

@@ -22,10 +22,7 @@ import threading
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.chaos import inject as chaos_inject
 from repro.chaos.inject import (ChaosKernelFault, ChaosStepFault, activate,

@@ -2,10 +2,7 @@
 import dataclasses
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # bare env: deterministic fallback shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.energy import (ALL_DATAFLOWS, DEFAULT_ARRAY, Dataflow,
                                E2ATSTSimulator, Inner, MMOp, Outer,

@@ -118,6 +118,41 @@ def test_bn_fwd(m, d, dtype):
     assert jnp.allclose(sq_k, sq_r, atol=1e-5)
 
 
+@pytest.mark.parametrize("m,block_m", [(1100, 256), (512, 128), (37, 8)])
+def test_bn_row_tiled_matches_ref(m, block_m):
+    """Statistics accumulated over row blocks — ragged last block
+    included — equal the single-block eq. 13-23 oracle."""
+    x = jax.random.normal(KEY, (m, 96)) * 2 + 0.5
+    gamma = jax.random.uniform(jax.random.PRNGKey(5), (96,)) + 0.5
+    beta = jax.random.normal(jax.random.PRNGKey(6), (96,))
+    g = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+    got = fused_bn.bn_fwd(x, gamma, beta, block_m=block_m)
+    want = ref.bn_fwd_ref(x, gamma, beta)
+    for a, b in zip(got, want):
+        assert jnp.allclose(a, b, atol=1e-5)
+    _, mu, sq = want
+    got = fused_bn.bn_bwd(g, x, gamma, mu, sq, block_m=block_m)
+    for a, b in zip(got, ref.bn_bwd_ref(g, x, gamma, mu, sq)):
+        assert jnp.allclose(a, b, atol=1e-4)
+
+
+@pytest.mark.parametrize("c", [64, 576, 2048, 4096])
+def test_spike_pack_bit_planes(c):
+    """Byte j of a pack group holds column b*group/8 + j in bit b; groups
+    are 1024 columns where they divide C, else all of C."""
+    from repro.kernels.spike_matmul import pack_group
+
+    sp = (jax.random.uniform(KEY, (3, c)) < 0.5).astype(jnp.float32)
+    g = pack_group(c)
+    packed = spike_pack(sp).astype(jnp.int32)
+    for col in (0, 1, g // 8 + 3, c - 1):
+        grp, within = divmod(col, g)
+        byte = grp * (g // 8) + within % (g // 8)
+        bit = within // (g // 8)
+        assert jnp.array_equal((packed[:, byte] >> bit) & 1,
+                               sp[:, col].astype(jnp.int32))
+
+
 def test_bn_bwd_matches_eq19_23_and_autodiff():
     x = jax.random.normal(KEY, (300, 64)) * 2 + 0.5
     gamma = jax.random.uniform(jax.random.PRNGKey(5), (64,)) + 0.5

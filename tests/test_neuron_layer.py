@@ -15,10 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # bare env: deterministic fallback shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.lif import LIFConfig, lif_scan
 from repro.core.policy import ExecutionPolicy, available_impls, named_policy

@@ -77,7 +77,7 @@ def test_continuous_matches_solo(name, spiking):
     assert sorted(r.uid for r in done) == list(range(5))
     for r in done:
         assert_greedy_parity(params, cfg, r)
-    assert engine.trace_count() in (1, None)   # the single-trace contract
+    assert engine.trace_count() == 1   # the single-trace contract
 
 
 def test_admit_mid_flight_into_vacated_slot():

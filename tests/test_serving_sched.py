@@ -18,10 +18,7 @@ import random
 
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.serving.scheduler import FIFOScheduler, Request, SlotError
 
