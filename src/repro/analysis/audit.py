@@ -116,8 +116,7 @@ def audit_spikingformer_plans(presets: Sequence[str] | None = None,
                     if r.effective not in FUSED_EPILOGUE_IMPLS:
                         continue
                     t, m, c, k = geoms[r.site]
-                    packed = "dense arm" not in r.note
-                    need = train_arm_vmem_bytes(t, m, c, k, packed)
+                    need = train_arm_vmem_bytes(t, m, c, k, r.packed)
                     if need > TRAIN_ARM_VMEM_BUDGET:
                         findings.append(warning(
                             "audit.plan.vmem",

@@ -221,7 +221,9 @@ class SiteDecision:
     ``expected`` marks a *structural* demotion the model shape dictates by
     design (e.g. the float-image first tokenizer stage cannot ride the
     spike-packed conv) — reported at INFO, unlike constraint violations
-    (ragged pack dims), which stay warnings.
+    (ragged pack dims), which stay warnings. ``packed`` says whether the
+    effective impl consumes bit-packed spikes (a fused-epilogue megakernel
+    on its dense arm does not).
     """
 
     site: str
@@ -230,6 +232,7 @@ class SiteDecision:
     effective: str
     note: str = ""
     expected: bool = False
+    packed: bool = False
 
 
 def plan_sites(policy: ExecutionPolicy,
@@ -268,26 +271,31 @@ def plan_sites(policy: ExecutionPolicy,
         trailing_lif = spec[4] if len(spec) > 4 else True
         requested = policy.resolve(site, op)
         effective, notes, violation = requested, [], False
+        ragged = dim is not None and dim % 8 != 0
         ffb = fused_epilogue_fallback(op, requested)
         if ffb is not None and not trailing_lif:
             effective = ffb
             notes.append(f"no trailing LIF at this site -> {ffb}")
         fb = packed_fallback(op, effective)
+        packed = False
         if fb is not None:
             if not spike_operand:
                 effective = fb
                 notes.append(f"float (non-spike) operand -> {fb}")
-            elif dim is not None and dim % 8 != 0:
+            elif ragged:
                 effective = fb
                 notes.append(f"pack dim {dim} % 8 != 0 -> {fb}")
                 violation = True
+            else:
+                packed = True
         elif effective in FUSED_EPILOGUE_IMPLS:
             # No demotion: the megakernel's dense arm serves the site in
             # the same single launch; only the packed HBM traffic is lost.
+            packed = spike_operand and not ragged
             if not spike_operand:
                 notes.append("float (non-spike) operand -> dense arm "
                              "(still fused)")
-            elif dim is not None and dim % 8 != 0:
+            elif ragged:
                 notes.append(f"pack dim {dim} % 8 != 0 -> dense arm "
                              f"(still fused)")
                 violation = True
@@ -296,7 +304,7 @@ def plan_sites(policy: ExecutionPolicy,
         if check_registry:
             get_kernel(op, effective)   # raises on unknown impl
         rows.append(SiteDecision(site, op, requested, effective, note,
-                                 expected))
+                                 expected, packed))
     if check_registry:
         sites = {spec[0] for spec in site_specs}
         known = sites | set(OPS)

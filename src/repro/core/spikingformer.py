@@ -241,15 +241,14 @@ class SpikingFormerConfig:
 
         if row.effective not in FUSED_EPILOGUE_IMPLS:
             return row
-        packed = "dense arm" not in row.note
         reason = train_arm_vmem_demotion(
-            *fused_site_geometries(self, batch)[row.site], packed,
+            *fused_site_geometries(self, batch)[row.site], row.packed,
             self.policy)
         if reason is None:
             return row
         pipeline = (fused_epilogue_fallback(row.op, row.effective)
                     if row.op == "linear_bn"
-                    else "pallas_packed" if packed else "pallas")
+                    else "pallas_packed" if row.packed else "pallas")
         note = (f"{reason} at batch {batch} -> {pipeline} in train "
                 f"(eval keeps the single launch)")
         return dataclasses.replace(

@@ -5,6 +5,10 @@ import subprocess
 import sys
 from pathlib import Path
 
+import jax
+import pytest
+
+import chip_diag
 import chip_smoke
 
 ROOT = Path(chip_smoke.__file__).resolve().parent
@@ -29,6 +33,54 @@ def test_train_phase_runs_every_arm_cleanly():
     assert records.failures(plans) == []
     loss0 = {arm: run["losses"][0] for arm, run in runs.items()}
     assert all(abs(v - loss0["jnp"]) < 1e-4 for v in loss0.values())
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    from repro.launch.mesh import make_test_mesh
+
+    return make_test_mesh(1, 1, devices=jax.devices()[:1])
+
+
+@pytest.fixture(scope="module")
+def jnp_passes(mesh):
+    return chip_smoke.composed_run(chip_smoke.arm_config("jnp", PRESET),
+                                   BATCH, mesh)
+
+
+@pytest.mark.parametrize("arm", ["pallas", "pallas-full"])
+def test_composed_phase_agrees_with_jnp(arm, mesh, jnp_passes):
+    got = chip_smoke.composed_run(chip_smoke.arm_config(arm, PRESET), BATCH,
+                                  mesh)
+    assert set(got) == set(jnp_passes)
+    assert chip_smoke._gap_failures(
+        arm, chip_smoke.composed_gaps(got, jnp_passes)) == []
+
+
+@pytest.mark.parametrize("plant", sorted(chip_diag.PLANTS))
+def test_composed_phase_fails_on_a_planted_bn_fault(plant, mesh, jnp_passes):
+    with chip_diag.PLANTS[plant]():
+        got = chip_smoke.composed_run(chip_smoke.arm_config("pallas", PRESET),
+                                      BATCH, mesh)
+    assert chip_smoke._gap_failures(
+        plant, chip_smoke.composed_gaps(got, jnp_passes)) != []
+
+
+def test_four_chip_check_passes_sharded_and_fails_dropped_reductions():
+    """On four virtual CPU devices: the sharded passes agree with one
+    device's, and one shard's gradients alone lie outside the bound (the
+    function fails unless both hold)."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    code = ("import chip_smoke; chip_smoke._import_repro(); import jax; "
+            "print(chip_smoke.sharding_failures("
+            f"chip_smoke.arm_config('jnp', {PRESET!r}), 8, jax.devices()))")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    lines = proc.stdout.splitlines()
+    assert "sharded vs single" in proc.stdout and "dropped" in proc.stdout
+    assert lines[-1] == "[]"
 
 
 def test_arm_failures_flag_nan_and_breaker_trips():

@@ -156,4 +156,5 @@ def test_batch_sizing_compiles_the_train_step(v5e):
     need = chip_smoke.step_bytes(cfg, 4, v5e)
     assert need > 0
     assert chip_smoke.choose_batch({"pallas-full": cfg}, v5e, 2 * need,
-                                   candidates=(64, 4)) == 4
+                                   candidates=(64, 4)) == (
+                                       4, {"pallas-full": need})
