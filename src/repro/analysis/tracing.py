@@ -1,5 +1,6 @@
-"""Trace-count guards: fail loudly when jit recompiles more than planned.
+"""Trace-count guards and host spans.
 
+Trace-count guards fail loudly when jit recompiles more than planned.
 The serving engine's single-trace contract (ONE jit trace for the engine's
 lifetime, ``docs/SERVING.md``) was asserted ad hoc via the jitted step's
 ``_cache_size()``. This module generalizes that into a reusable guard so
@@ -17,20 +18,36 @@ Two mechanisms, used automatically:
 
 * with explicit jitted callables, the per-function compile-cache size
   (``fn._cache_size()``) before/after the block;
-* with no callables, a process-global compile counter hooked off jax's
-  compilation log records, covering jits created *inside* the block.
+* with no callables, a process-global compile counter listening on jax's
+  documented ``jax.monitoring`` compile event, covering jits created
+  *inside* the block.
+
+Host spans (:func:`span`) name a stretch of host work, such as placing a
+batch or waiting for a step's loss. Each span is a
+``jax.profiler.TraceAnnotation``, so in a profiler trace it lies on the
+same clock as the device's ops; it is also always added to an in-process
+registry (count, total and longest seconds per name) that
+:func:`span_stats` reads, whether a profiler runs or not:
+
+    with span("data.place"):
+        batch = place_batch(host_batch, mesh)
+    span_stats()["data.place"]   # {"count": 1, "total_s": ..., "max_s": ...}
 """
 from __future__ import annotations
 
 import contextlib
-import logging
+import threading
+import time
 from typing import Any, Callable, Iterator
 
-__all__ = ["assert_trace_count", "compile_counter", "trace_count"]
+import jax
 
-#: Logger jax emits per-compilation records on, at DEBUG.
-_DISPATCH_LOGGER = "jax._src.dispatch"
-_COMPILE_MARKER = "Finished XLA compilation"
+__all__ = ["assert_trace_count", "compile_counter", "reset_spans", "span",
+           "span_stats", "trace_count"]
+
+#: The ``jax.monitoring`` duration event jax records once per executable
+#: it builds (compiled, or read from the persistent compilation cache).
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def trace_count(fn: Callable[..., Any]) -> int:
@@ -38,35 +55,23 @@ def trace_count(fn: Callable[..., Any]) -> int:
     return fn._cache_size()
 
 
-class _CompileCountHandler(logging.Handler):
-    def __init__(self) -> None:
-        super().__init__(level=logging.DEBUG)
-        self.count = 0
-
-    def emit(self, record: logging.LogRecord) -> None:
-        if _COMPILE_MARKER in record.getMessage():
-            self.count += 1
-
-
 @contextlib.contextmanager
 def compile_counter() -> Iterator[Callable[[], int]]:
     """Context manager yielding a zero-argument callable that returns the
     number of XLA compilations since the block was entered (process-global,
     any jit)."""
-    log = logging.getLogger(_DISPATCH_LOGGER)
-    handler = _CompileCountHandler()
-    prev_level = log.level
-    log.addHandler(handler)
-    # jax logs compiles at DEBUG unless jax_log_compiles promotes them;
-    # lower only this logger (records still propagate to root, whose
-    # WARNING-level handlers ignore them — no console noise).
-    if log.getEffectiveLevel() > logging.DEBUG:
-        log.setLevel(logging.DEBUG)
+    count = 0
+
+    def listen(event: str, duration: float, **_: Any) -> None:
+        nonlocal count
+        if event == COMPILE_EVENT:
+            count += 1
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
     try:
-        yield lambda: handler.count
+        yield lambda: count
     finally:
-        log.removeHandler(handler)
-        log.setLevel(prev_level)
+        jax.monitoring.unregister_event_duration_listener(listen)
 
 
 @contextlib.contextmanager
@@ -97,3 +102,49 @@ def _check(got: int, want: int, exact: bool, what: str) -> None:
             f"trace-count guard: {what} compiled {got} trace(s), "
             f"expected {bound} {want} — a retrace regression (unstable "
             f"static arg hash, or shapes varying per call?)")
+
+
+_SPANS: dict[str, list[float]] = {}      # name -> [count, total_s, max_s]
+_SPANS_LOCK = threading.Lock()
+
+
+class span:
+    """``with span(name):`` -- a host span: a profiler annotation named
+    ``name``, and one more entry of ``name`` in the registry that
+    :func:`span_stats` reads. Spans nest; each one counts its own time.
+    Costs two clock reads and a dict update when no profiler runs."""
+
+    __slots__ = ("name", "_annotation", "_t0")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self._annotation = jax.profiler.TraceAnnotation(name)
+
+    def __enter__(self) -> "span":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        dt = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        with _SPANS_LOCK:
+            rec = _SPANS.setdefault(self.name, [0, 0.0, 0.0])
+            rec[0] += 1
+            rec[1] += dt
+            rec[2] = max(rec[2], dt)
+
+
+def span_stats() -> dict[str, dict[str, float]]:
+    """Snapshot of the span registry: name -> ``count``, ``total_s`` and
+    ``max_s`` of every span of that name since the process started (or
+    since :func:`reset_spans`)."""
+    with _SPANS_LOCK:
+        return {name: {"count": int(c), "total_s": t, "max_s": m}
+                for name, (c, t, m) in _SPANS.items()}
+
+
+def reset_spans() -> None:
+    """Empty the span registry."""
+    with _SPANS_LOCK:
+        _SPANS.clear()

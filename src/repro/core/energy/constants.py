@@ -95,7 +95,7 @@ class Sparsity:
     s_pg: float = 0.50     # membrane-potential-gradient sparsity
 
 
-# --- TPU v5e roofline constants (for launch/roofline.py, not the ASIC sim) --
+# --- TPU v5e roofline constants (for tune/oracle.py, not the ASIC sim) ---
 TPU_PEAK_FLOPS_BF16 = 197e12        # per chip
 TPU_HBM_BW = 819e9                  # bytes/s per chip
 TPU_ICI_BW = 50e9                   # bytes/s per link

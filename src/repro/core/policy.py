@@ -426,15 +426,24 @@ def dispatch_site(site: str, op: str, impl: str, invoke: Callable[[], Any],
     megakernel absorbs the trailing LIF; its fallback is the multi-launch
     pipeline, not a same-signature impl swap) — the call site supplies a
     thunk that knows how to run its own reference path.
+
+    Both thunks run inside ``jax.named_scope(site)``, so every op the site
+    builds carries the site's name in its metadata (forward and, as
+    ``transpose(jvp(<site>))``, backward), whatever implementation runs
+    it. The scope only names: it adds no op to the compiled program.
     """
+    import jax
+
     from repro.chaos import inject as _chaos_inject
     guarded = (fallback_invoke is not None and fallback_impl is not None
                and fallback_impl != impl)
     if guarded and site in _BREAKER_TRIPS:
-        return fallback_invoke()
+        with jax.named_scope(site):
+            return fallback_invoke()
     try:
         _chaos_inject.kernel_fault(site)
-        return invoke()
+        with jax.named_scope(site):
+            return invoke()
     except (KeyboardInterrupt, SystemExit):
         raise
     except Exception as e:
@@ -446,7 +455,8 @@ def dispatch_site(site: str, op: str, impl: str, invoke: Callable[[], Any],
             "circuit breaker: site %s impl %r raised at dispatch "
             "(%s: %s) — demoted to %r for the rest of the run",
             site, impl, type(e).__name__, e, fallback_impl)
-        return fallback_invoke()
+        with jax.named_scope(site):
+            return fallback_invoke()
 
 
 def dispatch_kernel(site: str, op: str, impl: str, *args: Any) -> Any:

@@ -694,14 +694,19 @@ def spikingformer_apply(params: Params, state: State, images: jax.Array,
     """images: (T,B,H,W,C) or (B,H,W,C) (static image, repeated over T).
 
     Returns (logits (B, num_classes), new_state).
+
+    The ops fall under the named scopes ``tokenizer``, ``blocks`` and
+    ``head`` (and each site's own scope inside them), so a profile of the
+    compiled step attributes device time to them.
     """
-    if images.ndim == 4:  # static dataset: replicate over time (direct coding)
-        images = jnp.broadcast_to(images[None],
-                                  (cfg.time_steps,) + images.shape)
-    images = shard(images, None, BATCH, None, None, None)
-    x, s_tok = tokenizer_apply(params["tokenizer"], state["tokenizer"], images,
-                               cfg, train=train)
-    x = shard(x, None, BATCH, None, None)
+    with jax.named_scope("tokenizer"):
+        if images.ndim == 4:  # static dataset: replicate over time
+            images = jnp.broadcast_to(images[None],
+                                      (cfg.time_steps,) + images.shape)
+        images = shard(images, None, BATCH, None, None, None)
+        x, s_tok = tokenizer_apply(params["tokenizer"], state["tokenizer"],
+                                   images, cfg, train=train)
+        x = shard(x, None, BATCH, None, None)
 
     def layer(x, ps):
         p, s = ps
@@ -710,10 +715,13 @@ def spikingformer_apply(params: Params, state: State, images: jax.Array,
 
     if cfg.remat:
         layer = jax.checkpoint(layer)
-    x, s_blocks = jax.lax.scan(layer, x, (params["blocks"], state["blocks"]))
-    # eq. 7: GAP over tokens, rate-decode over time, then FC.
-    feat = shard(jnp.mean(x, axis=(0, 2)), BATCH, None)   # (B, D)
-    logits = linear_apply(params["head"], feat) + params["head"]["b"]
+    with jax.named_scope("blocks"):
+        x, s_blocks = jax.lax.scan(layer, x,
+                                   (params["blocks"], state["blocks"]))
+    with jax.named_scope("head"):
+        # eq. 7: GAP over tokens, rate-decode over time, then FC.
+        feat = shard(jnp.mean(x, axis=(0, 2)), BATCH, None)   # (B, D)
+        logits = linear_apply(params["head"], feat) + params["head"]["b"]
     return logits.astype(jnp.float32), {"tokenizer": s_tok, "blocks": s_blocks}
 
 
@@ -730,8 +738,9 @@ def spikingformer_loss(params, state, images, labels, cfg: SpikingFormerConfig):
     :func:`spikingformer_loss_jit`."""
     logits, new_state = spikingformer_apply(params, state, images, cfg,
                                             train=True)
-    loss = cross_entropy(logits, labels)
-    acc = jnp.mean((jnp.argmax(logits, -1) == labels).astype(jnp.float32))
+    with jax.named_scope("head"):
+        loss = cross_entropy(logits, labels)
+        acc = jnp.mean((jnp.argmax(logits, -1) == labels).astype(jnp.float32))
     return loss, (new_state, {"loss": loss, "accuracy": acc})
 
 

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.analysis.tracing import compile_counter, span
 from repro.chaos import inject as chaos_inject
 from repro.configs.registry import get_config, reduced
 from repro.core.policy import log_fallbacks
@@ -104,6 +105,13 @@ def _drive(mesh, *, start: int, steps: int, step_once, save, log_line,
     after ``final_join_timeout`` seconds raises
     ``ckpt.CheckpointWriteTimeout`` so orchestrators see a nonzero exit
     instead of a scrolled-past warning.
+
+    Each step runs inside ``jax.profiler.StepTraceAnnotation("train",
+    step_num=step)``, with the loss read in the host span ``train.sync``,
+    so in a profiler trace every span of a step carries its step id. The
+    straggler monitor times a step up to that read (device included). A
+    step after the first that builds an executable prints
+    ``[recompile] step N: k executables``.
     """
     monitor = StragglerMonitor(
         on_straggler=lambda dt, med: print(
@@ -113,13 +121,20 @@ def _drive(mesh, *, start: int, steps: int, step_once, save, log_line,
     history = []
     pending_save = None
 
-    with use_mesh(mesh):
+    with use_mesh(mesh), compile_counter() as compiles:
         for step in range(start, steps):
             chaos_inject.step_fault(step)
+            built = compiles()
             monitor.step_start()
-            metrics = step_once(step)
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                metrics = step_once(step)
+                with span("train.sync"):
+                    loss = float(metrics["loss"])
             monitor.step_end()
-            history.append(float(metrics["loss"]))
+            history.append(loss)
+            if step > start and compiles() > built:
+                print(f"[recompile] step {step}: {compiles() - built} "
+                      f"executables", flush=True)
             if nf_guard.observe(float(metrics.get("nonfinite", 0.0)) > 0.0,
                                 step):
                 print(f"[guard] step {step} non-finite loss/grads — state "

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis.tracing import span
+
 
 @dataclasses.dataclass(frozen=True)
 class DataConfig:
@@ -42,14 +44,15 @@ class SyntheticLM:
               host_count: int = 1) -> dict[str, np.ndarray]:
         cfg = self.cfg
         local = cfg.global_batch // host_count
-        rng = np.random.default_rng(
-            (cfg.seed, step, host_index))
-        toks = np.empty((local, cfg.seq_len + 1), np.int32)
-        toks[:, 0] = rng.integers(0, cfg.vocab_size, size=local)
-        choices = rng.integers(0, cfg.branching,
-                               size=(local, cfg.seq_len))
-        for t in range(cfg.seq_len):
-            toks[:, t + 1] = self.table[toks[:, t], choices[:, t]]
+        with span("data.make"):
+            rng = np.random.default_rng(
+                (cfg.seed, step, host_index))
+            toks = np.empty((local, cfg.seq_len + 1), np.int32)
+            toks[:, 0] = rng.integers(0, cfg.vocab_size, size=local)
+            choices = rng.integers(0, cfg.branching,
+                                   size=(local, cfg.seq_len))
+            for t in range(cfg.seq_len):
+                toks[:, t + 1] = self.table[toks[:, t], choices[:, t]]
         return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
 
     def iterator(self, start_step: int = 0, host_index: int = 0,
@@ -93,18 +96,19 @@ class SyntheticVision:
         cfg = self.cfg
         local = cfg.global_batch // host_count
         size = cfg.image_size
-        rng = np.random.default_rng((cfg.seed, step, host_index))
-        labels = rng.integers(0, min(4, cfg.num_classes),
-                              size=local).astype(np.int32)
-        imgs = rng.normal(0, 0.1, size=(local, size, size,
-                                        cfg.channels)).astype(np.float32)
-        half = size // 2
-        for i, lab in enumerate(labels):
-            y0 = (int(lab) // 2) * half
-            x0 = (int(lab) % 2) * half
-            imgs[i, y0:y0 + half, x0:x0 + half] += 1.0
-        if cfg.spikes:   # blob pixels (~1.0) fire, background noise doesn't
-            imgs = (imgs > 0.5).astype(np.float32)
+        with span("data.make"):
+            rng = np.random.default_rng((cfg.seed, step, host_index))
+            labels = rng.integers(0, min(4, cfg.num_classes),
+                                  size=local).astype(np.int32)
+            imgs = rng.normal(0, 0.1, size=(local, size, size,
+                                            cfg.channels)).astype(np.float32)
+            half = size // 2
+            for i, lab in enumerate(labels):
+                y0 = (int(lab) // 2) * half
+                x0 = (int(lab) % 2) * half
+                imgs[i, y0:y0 + half, x0:x0 + half] += 1.0
+            if cfg.spikes:   # blob pixels (~1.0) fire, background doesn't
+                imgs = (imgs > 0.5).astype(np.float32)
         return {"images": imgs, "labels": labels}
 
     def iterator(self, start_step: int = 0, host_index: int = 0,
@@ -116,11 +120,14 @@ class SyntheticVision:
 
 
 def place_batch(batch: dict[str, np.ndarray], mesh=None):
-    """Put a host-local batch onto the mesh with global-batch sharding."""
-    if mesh is None:
-        return {k: jnp.asarray(v) for k, v in batch.items()}
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    sharding = NamedSharding(mesh, P(batch_axes or None))
-    return {k: jax.device_put(jnp.asarray(v), sharding)
-            for k, v in batch.items()}
+    """Put a host-local batch onto the mesh with global-batch sharding
+    (host span ``data.place``)."""
+    with span("data.place"):
+        if mesh is None:
+            return {k: jnp.asarray(v) for k, v in batch.items()}
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        batch_axes = tuple(a for a in ("pod", "data")
+                           if a in mesh.axis_names)
+        sharding = NamedSharding(mesh, P(batch_axes or None))
+        return {k: jax.device_put(jnp.asarray(v), sharding)
+                for k, v in batch.items()}

@@ -103,14 +103,15 @@ def make_train_step(cfg: Any, opt_cfg: OptimizerConfig,
             grads = jax.tree.map(lambda g: g / microbatches, grads)
             loss = loss_sum / microbatches
             metrics = {"loss": loss}
-        new_params, new_opt, opt_metrics = adamw_update(
-            params, grads, opt_state, opt_cfg)
-        metrics = {**metrics, **opt_metrics}
-        if guard_nonfinite:
-            finite = _all_finite(loss, grads)
-            new_params = _select_tree(finite, new_params, params)
-            new_opt = _select_tree(finite, new_opt, opt_state)
-            metrics["nonfinite"] = 1.0 - finite.astype(jnp.float32)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = adamw_update(
+                params, grads, opt_state, opt_cfg)
+            metrics = {**metrics, **opt_metrics}
+            if guard_nonfinite:
+                finite = _all_finite(loss, grads)
+                new_params = _select_tree(finite, new_params, params)
+                new_opt = _select_tree(finite, new_opt, opt_state)
+                metrics["nonfinite"] = 1.0 - finite.astype(jnp.float32)
         return new_params, new_opt, metrics
 
     return train_step
@@ -157,17 +158,19 @@ def _make_vision_train_step(cfg, opt_cfg: OptimizerConfig,
             labels = jax.lax.with_sharding_constraint(labels, P(batch_axes_))
         grads, new_state, metrics = spikingformer_grad_step(
             params, state, images, labels, cfg)
-        new_params, new_opt, opt_metrics = adamw_update(
-            params, grads, opt_state, opt_cfg)
-        metrics = {**metrics, **opt_metrics}
-        if guard_nonfinite:
-            finite = _all_finite(metrics["loss"], grads)
-            new_params = _select_tree(finite, new_params, params)
-            # BN running statistics ride the forward pass, so a poisoned
-            # batch contaminates them too — roll them back with the rest.
-            new_state = _select_tree(finite, new_state, state)
-            new_opt = _select_tree(finite, new_opt, opt_state)
-            metrics["nonfinite"] = 1.0 - finite.astype(jnp.float32)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = adamw_update(
+                params, grads, opt_state, opt_cfg)
+            metrics = {**metrics, **opt_metrics}
+            if guard_nonfinite:
+                finite = _all_finite(metrics["loss"], grads)
+                new_params = _select_tree(finite, new_params, params)
+                # BN running statistics ride the forward pass, so a
+                # poisoned batch contaminates them too — roll them back
+                # with the rest.
+                new_state = _select_tree(finite, new_state, state)
+                new_opt = _select_tree(finite, new_opt, opt_state)
+                metrics["nonfinite"] = 1.0 - finite.astype(jnp.float32)
         return new_params, new_state, new_opt, metrics
 
     return train_step

@@ -1,8 +1,10 @@
 """Fault-tolerance machinery for 1000+ node runs.
 
-* StragglerMonitor — per-step wall-time tracking; steps slower than
-  ``threshold x`` the trailing median flag the host as a straggler and fire
-  a callback (eviction request / rescheduling in a real deployment).
+* StragglerMonitor — per-step wall-time tracking (the caller ends a step
+  after reading its result, so the time includes the device's); steps
+  slower than ``threshold x`` the trailing median flag the host as a
+  straggler and fire a callback (eviction request / rescheduling in a
+  real deployment).
 * PreemptionGuard — converts SIGTERM into a "checkpoint now" flag the train
   loop polls between steps (the standard TPU-preemption pattern).
 * ElasticPlan — given a failed/resized device set, computes the new mesh
@@ -16,6 +18,7 @@
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import signal
 import statistics
@@ -24,12 +27,20 @@ from typing import Callable
 
 
 class StragglerMonitor:
+    """Times steps from :meth:`step_start` to :meth:`step_end` and flags a
+    step slower than ``threshold`` x the median of the ``window`` steps
+    before it. End the step only after its result has been read back (the
+    loss as a Python float): JAX dispatches asynchronously, so a step
+    ended at dispatch times the host's enqueue, not the device's work.
+    Only the last ``window`` durations are kept."""
+
     def __init__(self, window: int = 32, threshold: float = 2.0,
                  on_straggler: Callable[[float, float], None] | None = None):
         self.window = window
         self.threshold = threshold
         self.on_straggler = on_straggler
-        self.durations: list[float] = []
+        self.durations: collections.deque[float] = collections.deque(
+            maxlen=window)
         self.flagged: list[int] = []
         self._t0: float | None = None
         self._step = 0
@@ -43,7 +54,7 @@ class StragglerMonitor:
         dt = time.monotonic() - self._t0
         self._t0 = None
         self._step += 1
-        hist = self.durations[-self.window:]
+        hist = list(self.durations)
         self.durations.append(dt)
         if len(hist) >= 8:
             med = statistics.median(hist)

@@ -99,3 +99,130 @@ def test_serving_engine_step_is_single_trace():
         done = engine.run_to_completion()
     assert sorted(r.uid for r in done) == [0, 1]
     assert engine.trace_count() == 1
+
+
+# ------------------------------ host spans ---------------------------------
+
+@pytest.fixture
+def spans():
+    from repro.analysis.tracing import reset_spans
+    reset_spans()
+    yield
+    reset_spans()
+
+
+def test_spans_count_total_and_longest(spans):
+    import time
+
+    from repro.analysis.tracing import span, span_stats
+    for pause in (0.001, 0.004, 0.002):
+        with span("t.step"):
+            time.sleep(pause)
+    s = span_stats()["t.step"]
+    assert s["count"] == 3
+    assert 0.007 <= s["total_s"] < 0.5
+    assert 0.004 <= s["max_s"] <= s["total_s"]
+
+
+def test_nested_spans_each_count_their_own_time(spans):
+    import time
+
+    from repro.analysis.tracing import span, span_stats
+    with span("t.outer"):
+        time.sleep(0.002)
+        for _ in range(2):
+            with span("t.inner"):
+                time.sleep(0.003)
+    s = span_stats()
+    assert s["t.outer"]["count"] == 1 and s["t.inner"]["count"] == 2
+    assert s["t.outer"]["total_s"] >= s["t.inner"]["total_s"] + 0.002
+    assert s["t.inner"]["total_s"] >= 0.006
+
+
+def test_a_span_records_when_its_block_raises(spans):
+    from repro.analysis.tracing import span, span_stats
+    with pytest.raises(ValueError):
+        with span("t.fails"):
+            raise ValueError("boom")
+    assert span_stats()["t.fails"]["count"] == 1
+
+
+def test_reset_and_snapshot_are_independent(spans):
+    from repro.analysis.tracing import reset_spans, span, span_stats
+    with span("t.once"):
+        pass
+    snap = span_stats()
+    reset_spans()
+    assert span_stats() == {}
+    assert snap["t.once"]["count"] == 1
+
+
+def test_data_place_span_lies_inside_its_caller_in_a_profiler_trace(
+        spans, tmp_path):
+    """The program's span is a profiler annotation on the caller's thread,
+    on the clock of the enclosing annotation."""
+    import glob
+
+    import numpy as np
+    from jax.profiler import ProfileData
+
+    from repro.analysis.tracing import span_stats
+    from repro.train.data import place_batch
+
+    batch = {"x": np.ones((4, 8), np.float32)}
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            with jax.profiler.TraceAnnotation("caller.place"):
+                place_batch(batch)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {"caller.place": [], "data.place": []}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in events:
+                    events[e.name].append((line.name, e.start_ns, e.end_ns))
+    assert len(events["data.place"]) == 3 == len(events["caller.place"])
+    for line, s, e in events["data.place"]:
+        assert any(line == ln and cs <= s and e <= ce
+                   for ln, cs, ce in events["caller.place"])
+    assert span_stats()["data.place"]["count"] == 3
+
+
+def test_compile_counter_listens_on_the_monitoring_event():
+    from repro.analysis.tracing import COMPILE_EVENT
+    with compile_counter() as count:
+        jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 0.1)
+        jax.monitoring.record_event_duration_secs("/jax/other/event", 0.1)
+        assert count() == 1
+    jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 0.1)
+    assert count() == 1       # the listener is gone after the block
+
+
+def test_spans_from_many_threads_lose_no_update(spans):
+    import os
+    import sys
+    import threading
+
+    from repro.analysis.tracing import span, span_stats
+    workers, each = 2 * (os.cpu_count() or 2) + 2, 300
+
+    def work():
+        for _ in range(each):
+            with span("t.threads"):
+                pass
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work) for _ in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert span_stats()["t.threads"]["count"] == workers * each

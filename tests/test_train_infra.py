@@ -192,6 +192,61 @@ def test_straggler_monitor_flags_outlier():
     assert len(hits) == 1
 
 
+def test_straggler_monitor_keeps_only_its_window():
+    mon = StragglerMonitor(window=8)
+    for _ in range(50):
+        mon.step_start()
+        mon.step_end()
+    assert len(mon.durations) == 8
+
+
+class _SlowLoss:
+    """A loss whose read on the host blocks, as reading a device value
+    waits for the step that computes it."""
+
+    def __init__(self, seconds):
+        self.seconds = seconds
+
+    def __float__(self):
+        time.sleep(self.seconds)
+        return 1.0
+
+
+def _drive_fake(step_once, steps, capsys):
+    from repro.launch.mesh import make_test_mesh
+    from repro.launch.train import _drive
+    history = _drive(make_test_mesh(1, 1), start=0, steps=steps,
+                     step_once=step_once, save=None,
+                     log_line=lambda step, m: "", log_every=10**6,
+                     ckpt_every=10**6, ckpt_dir=None)
+    return history, capsys.readouterr().out
+
+
+def test_drive_flags_a_step_whose_loss_read_blocks(capsys):
+    """Dispatch returns at once; the slow step shows only when its loss is
+    read, and ``_drive``'s straggler monitor times up to that read."""
+    def step_once(step):
+        return {"loss": _SlowLoss(0.15 if step == 12 else 0.002)}
+
+    history, out = _drive_fake(step_once, 14, capsys)
+    assert history == [1.0] * 14
+    flagged = [ln for ln in out.splitlines() if ln.startswith("[straggler]")]
+    assert len(flagged) == 1
+    assert float(flagged[0].split()[3].rstrip("s")) >= 0.15
+
+
+def test_drive_names_a_step_that_recompiles(capsys):
+    total = jax.jit(lambda x: x.sum())
+
+    def step_once(step):
+        width = 4 if step < 3 else 5       # step 3 brings a new shape
+        return {"loss": total(np.ones((width,), np.float32))}
+
+    _, out = _drive_fake(step_once, 5, capsys)
+    lines = [ln for ln in out.splitlines() if ln.startswith("[recompile]")]
+    assert lines == ["[recompile] step 3: 1 executables"]
+
+
 def test_elastic_plan_drops_pod_first():
     plan = ElasticPlan.after_failure((2, 16, 16), ("pod", "data", "model"),
                                      healthy_devices=256)
