@@ -5,8 +5,12 @@ Model = Spiking Tokenizer (conv downsampling + spike encoding, eq. 4)
       + GAP + FC classification head (eq. 7).
 
 Training is BPTT (paper §II-C): the time axis is scanned (``lax.scan``) and
-autodiff through the LIF surrogate reproduces eq. 12. Blocks are homogeneous
-and scanned over depth so the lowered HLO is O(1) in L.
+autodiff through the LIF surrogate reproduces eq. 12. Blocks are homogeneous:
+their parameters and BN state are stored stacked over depth ([L, ...]
+leaves), but they run as a Python loop over the L blocks, not a scan. Under
+BPTT a depth scan copies every residual the backward needs into an
+[L, ...] buffer and slices it out again; in the loop each residual stays
+where its block wrote it.
 """
 from __future__ import annotations
 
@@ -67,7 +71,7 @@ class SpikingFormerConfig:
     qk_first: bool = True             # paper-faithful (QK^T)V order
     attn_scale: float = 0.125
     dtype: Any = jnp.float32
-    remat: bool = False               # checkpoint each block over the scan
+    remat: bool = False               # checkpoint each block
     # Temporal tiling (the paper's temporal blocking): every LIF scan splits
     # its T axis into remat'd chunks of this length with the (U, S) carry
     # threaded across chunk boundaries — stored BPTT residuals scale with
@@ -365,7 +369,7 @@ def spikingformer_param_specs(cfg: SpikingFormerConfig):
     SMLP-A column-parallel (output features over "model", with their BN
     leaves sharded alike), Z-projection and SMLP-B row-parallel (input
     features over "model", BN replicated). The vmapped block leaves carry a
-    leading L scan axis that stays unsharded (``spikingformer_scan_dims``
+    leading L depth axis that stays unsharded (``spikingformer_scan_dims``
     tells ``apply_fsdp`` to skip it). Tokenizer convs and the head are
     replicated — FSDP may still shard them over "data"."""
     rep = P(None)
@@ -424,8 +428,8 @@ def lif_residual_accounting(cfg: SpikingFormerConfig, batch: int
 
 
 def spikingformer_scan_dims(specs):
-    """Per-leaf count of leading vmapped/scan dims ``apply_fsdp`` must not
-    shard: 1 for the stacked block leaves, 0 elsewhere."""
+    """Per-leaf count of leading stacked dims ``apply_fsdp`` must not
+    shard: 1 for the block leaves stacked over depth, 0 elsewhere."""
     def n_scan(path, _):
         return 1 if any(getattr(p, "key", None) == "blocks" for p in path) \
             else 0
@@ -689,6 +693,17 @@ def init_spikingformer(key, cfg: SpikingFormerConfig):
     return params, state
 
 
+def _unstack(tree) -> list:
+    """Split every [L, ...] leaf of a stacked block tree once (one
+    ``lax.split``, whose transpose is one concatenate) into L per-block
+    trees."""
+    leaves, treedef = jax.tree.flatten(tree)
+    n = leaves[0].shape[0]
+    parts = [[jnp.squeeze(c, 0) for c in jnp.split(leaf, n)]
+             for leaf in leaves]
+    return [treedef.unflatten([p[i] for p in parts]) for i in range(n)]
+
+
 def spikingformer_apply(params: Params, state: State, images: jax.Array,
                         cfg: SpikingFormerConfig, *, train: bool):
     """images: (T,B,H,W,C) or (B,H,W,C) (static image, repeated over T).
@@ -708,16 +723,18 @@ def spikingformer_apply(params: Params, state: State, images: jax.Array,
                                    images, cfg, train=train)
         x = shard(x, None, BATCH, None, None)
 
-    def layer(x, ps):
-        p, s = ps
-        y, s_new = block_apply(p, s, x, cfg.block, train=train)
-        return y, s_new
+    def layer(p, s, x):
+        return block_apply(p, s, x, cfg.block, train=train)
 
     if cfg.remat:
         layer = jax.checkpoint(layer)
     with jax.named_scope("blocks"):
-        x, s_blocks = jax.lax.scan(layer, x,
-                                   (params["blocks"], state["blocks"]))
+        new_states = []
+        for p, s in zip(_unstack(params["blocks"]),
+                        _unstack(state["blocks"])):
+            x, s_new = layer(p, s, x)
+            new_states.append(s_new)
+        s_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *new_states)
     with jax.named_scope("head"):
         # eq. 7: GAP over tokens, rate-decode over time, then FC.
         feat = shard(jnp.mean(x, axis=(0, 2)), BATCH, None)   # (B, D)

@@ -50,9 +50,9 @@ def apply_fsdp(specs, shapes, mesh, min_elems: int = 1 << 20,
 
     ``scan_dims`` (optional) is a pytree of ints matching ``specs``: the
     number of leading scan/vmap dims per leaf that must never be sharded —
-    the Spikingformer's stacked block leaves carry a leading L axis that is
-    scanned over depth, and slicing it per layer would turn every scan step
-    into a gather."""
+    the Spikingformer's stacked block leaves carry a leading L (depth) axis
+    that the model splits into per-block leaves locally, and sharding it
+    would turn that split into a gather."""
     if axis not in mesh.axis_names:
         return specs
     size = dict(zip(mesh.axis_names, mesh.axis_sizes))[axis]

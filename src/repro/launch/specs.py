@@ -59,7 +59,7 @@ def spikingformer_structs(cfg, mesh, fsdp_min_elems: int = 1 << 20):
 
     The single source of the vision sharding plan: logical specs from
     ``spikingformer_param_specs`` are sanitized against the mesh and FSDP'd
-    over "data" (the stacked block leaves keep their leading L scan axis
+    over "data" (the stacked block leaves keep their leading L depth axis
     unsharded via ``spikingformer_scan_dims``). Used by
     ``launch.train.build_spikingformer_state``, the vision dry-run cell and
     ``SpikingFormerConfig.describe_execution(mesh)``.
