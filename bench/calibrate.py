@@ -98,12 +98,13 @@ def main(argv=None) -> int:
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     kinds = [("program", None, SEEDS),
-             ("control", faults.control(cell.model), OTHER_SEEDS),
+             ("control", faults.control(cell), OTHER_SEEDS),
              ("half_batch", faults.half_batch, OTHER_SEEDS),
              ("unchanged", faults.unchanged, OTHER_SEEDS)]
     if cell.chips > 1:
-        kinds.append(("no_exchange", faults.no_exchange(cell.model),
+        kinds.append(("no_exchange", faults.no_exchange(cell),
                        OTHER_SEEDS))
+    throughput = cell.family.THROUGHPUT
     out = None
     if args.out:
         os.makedirs(args.out, exist_ok=True)
@@ -120,8 +121,8 @@ def main(argv=None) -> int:
                             **{k: v["value"] for k, v in
                                res["compared"].items()},
                             "setup_s": res["metrics"]["setup_s"]["value"],
-                            "images_per_s":
-                                res["metrics"]["images_per_s"]["value"]}
+                            throughput:
+                                res["metrics"][throughput]["value"]}
                 except Exception as e:  # a crashing control has failed
                     line = {"kind": kind, "seed": seed, "error": repr(e)}
                 line["wall_s"] = time.perf_counter() - t

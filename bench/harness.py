@@ -1,11 +1,11 @@
 """One run of one cell: set-up, the measured window, the comparison with
 the reference, and the result line.
 
-The window drives the program's jitted train step
-(``repro.train.loop.make_train_step``), built and fed as
-``repro.launch.train.train_vision`` builds and feeds it: state from
-``build_spikingformer_state`` on a ``make_test_mesh`` mesh, ``jax.jit(step,
-donate_argnums=(0, 1, 2))``, each batch placed by ``place_batch``, and the
+Nothing here depends on the model: what does comes from the cell's model
+family (``bench/families/<family>.py``, found by ``cells.find_cell``). The
+window drives the program's own pure train step, as the family builds it,
+on a ``make_test_mesh`` mesh: ``jax.jit(step, donate_argnums=(0, 1, 2))``,
+each batch from the family's generator placed by ``place_batch``, and the
 loss and the non-finite flag read on the host after every step. Set-up
 compiles that step, drives it through the first steps that the comparison
 reads, then warms it; the window then runs the same compiled step.
@@ -24,8 +24,7 @@ import numpy as np
 
 from bench import compare
 from bench.cells import Cell, metric_reader
-from bench.traffic import Traffic
-from bench.weights import leaf_name, make_params
+from bench.weights import make_params
 
 #: Steps after the first ones and before the window (same shapes).
 WARM_STEPS = 2
@@ -77,28 +76,6 @@ class Run:
     trace: object           # bench.trace.Summary of the traced steps
 
 
-def program_config(cell: Cell):
-    """The program's config object for ``cell`` (sizes from the
-    configuration file, policy and temporal tile from the traffic mix)."""
-    from repro.core.lif import LIFConfig
-    from repro.core.policy import named_policy
-    from repro.core.spikingformer import SpikingFormerConfig
-
-    return SpikingFormerConfig(
-        **cell.model["model"], lif=LIFConfig(**cell.model["lif"]),
-        time_chunk=cell.mix.get("time_chunk"),
-        policy=named_policy(cell.mix["policy"]))
-
-
-def optimizer_config(cell: Cell):
-    from repro.train.optimizer import OptimizerConfig
-
-    o = cell.model["optimizer"]
-    return OptimizerConfig(**{f.name: o[f.name] for f in
-                              dataclasses.fields(OptimizerConfig)
-                              if f.name in o})
-
-
 def planned_bytes(compiled) -> int:
     """Bytes the compiler plans for one call on the fullest device:
     arguments + outputs - aliases + temporaries."""
@@ -128,33 +105,31 @@ def _keep_shardings(fn, like):
 
 def reference_readings(cell: Cell, seed: int, structs, device, traffic):
     """The reference's readings of the first steps, on ``device``, at the
-    configuration's precision (float32 at XLA's default matmul precision).
-    ``structs`` are the program's (params, BN state) shapes."""
+    configuration's precision. ``structs`` are the program's (params,
+    state) shapes; the reference's optimizer state is AdamW's moments and
+    step count."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from bench.configs.spikingformer_reference import make_step
-
+    family = cell.family
     on = SingleDeviceSharding(device)
     params_s, state_s = (jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on), t)
         for t in structs)
-    start = lambda: make_params(params_s, seed)  # noqa: E731
+    start = lambda: make_params(params_s, seed, family.init)  # noqa: E731
     params = start()
-    state = jax.tree_util.tree_map_with_path(
-        lambda path, a: jax.device_put(
-            (jnp.ones if leaf_name(path).endswith("var") else jnp.zeros)(
-                a.shape, a.dtype), on), state_s)
+    state = family.reference_state(state_s)
     opt = {"m": jax.tree.map(jnp.zeros_like, params),
            "v": jax.tree.map(jnp.zeros_like, params),
            "step": jax.device_put(jnp.zeros((), jnp.int32), on)}
-    batches = [tuple(jax.device_put(b[k], on) for k in ("images", "labels"))
-               for b in (traffic.batch(i) for i in range(compare.FIRST_STEPS))]
+    batches = [traffic.inputs({k: jax.device_put(v, on)
+                               for k, v in traffic.batch(i).items()})
+               for i in range(compare.FIRST_STEPS)]
     readings, _ = compare.first_steps(
-        jax.jit(make_step(cell.model), donate_argnums=(0, 1, 2)), params,
-        state, opt, batches, cell.model["optimizer"]["beta1"],
-        cell.model["bn"]["momentum"], start)
+        jax.jit(family.reference_step(cell.model),
+                donate_argnums=(0, 1, 2)),
+        params, state, opt, batches, cell, start)
     return readings
 
 
@@ -166,27 +141,21 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     import jax
 
     from repro.launch.mesh import make_test_mesh, use_mesh
-    from repro.launch.train import build_spikingformer_state
     from repro.train.data import place_batch
-    from repro.train.loop import make_train_step
 
-    mix, batch = cell.mix, int(cell.mix["batch"])
-    cfg, opt_cfg = program_config(cell), optimizer_config(cell)
+    family, mix, batch = cell.family, cell.mix, int(cell.mix["batch"])
     mesh = make_test_mesh(mix["mesh"]["data"], mix["mesh"]["model"],
                           devices=devices)
-    traffic = Traffic(mix, cell.model["model"], seed)
+    traffic = family.traffic(mix, cell.model, seed)
     compiles = Compiles()
-    plan = {r.site: r.effective for r in cfg.execution_plan(batch)}
 
     def place(host):
-        placed = place_batch(host, mesh)
-        return placed["images"], placed["labels"]
+        return traffic.inputs(place_batch(host, mesh))
 
     with compiles, use_mesh(mesh):
-        params, state, opt, _ = build_spikingformer_state(cfg, mesh, opt_cfg)
+        (params, state, opt), fn, plan = family.program(cell, mesh)
         struct, state_struct = _structs(params), _structs(state)
-        params = make_params(struct, seed)
-        fn = make_train_step(cfg, opt_cfg, mesh=mesh)
+        params = make_params(struct, seed, family.init)
         if step_hook is not None:
             fn = _keep_shardings(step_hook(fn, mesh), (params, state, opt))
         step = jax.jit(fn, donate_argnums=(0, 1, 2))
@@ -194,8 +163,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         compiled = step.lower(params, state, opt, *firsts[0]).compile()
         hbm = planned_bytes(compiled)
         got, (params, state, opt) = compare.first_steps(
-            compiled, params, state, opt, firsts, opt_cfg.beta1,
-            cell.model["bn"]["momentum"], lambda: make_params(struct, seed))
+            compiled, params, state, opt, firsts, cell,
+            lambda: make_params(struct, seed, family.init))
         del firsts
         n = compare.FIRST_STEPS
         for i in range(n, n + WARM_STEPS):
@@ -217,10 +186,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
             with _span("bench.batch", tracing):
                 host = traffic.batch(step_no)
             with _span("bench.place", tracing):
-                images, labels = place(host)
+                inputs = place(host)
             with _span("bench.dispatch", tracing):
                 params, state, opt, m = compiled(params, state, opt,
-                                                 images, labels)
+                                                 *inputs)
             with _span("bench.read", tracing):
                 loss, nf = float(m["loss"]), float(m["nonfinite"])
             b = time.perf_counter()
@@ -271,7 +240,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
                    breakdown=summary.breakdown())
     else:
         e2e = {
-            "images_per_s": len(durations) * batch / (end - start),
+            family.THROUGHPUT:
+                len(durations) * traffic.samples / (end - start),
             "step_ms_p95": 1e3 * float(np.percentile(durations, 95)),
             "step_hbm_gib": hbm / GIB,
             "setup_s": setup_s,
@@ -281,7 +251,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
                             for m_ in cell.end_to_end},
                    device=device)
     out["compared"] = {k: {"value": numbers[k], "limit": cell.limits[k]}
-                       for k in compare.NUMBERS if k in cell.limits}
+                       for k in compare.NUMBERS
+                       if k in cell.limits and k in numbers}
     return out
 
 

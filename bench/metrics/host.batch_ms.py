@@ -1,7 +1,7 @@
 """host.batch_ms: host milliseconds per step spent making the step's batch
 (``bench.batch``) and placing it on the mesh (``bench.place``), from the
-harness's spans in the trace. Moves images_per_s while the device waits
-for the host."""
+harness's spans in the trace. Moves the throughput while the device
+waits for the host."""
 
 
 def read(run):

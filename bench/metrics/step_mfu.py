@@ -1,13 +1,12 @@
 """step_mfu: per cent of the chips' bf16 peak that the step's model FLOPs
-reach over the traced window (bench/work.py step_flops, the same count
-whatever implements a site). XLA runs these float32 matmuls at its default
-precision, one bf16 pass, so the bf16 peak is the ceiling."""
-
-from bench import work
+reach over the traced window (``step_flops`` of the cell's model family,
+the same count whatever implements a site). XLA runs these float32
+matmuls at its default precision, one bf16 pass, so the bf16 peak is the
+ceiling."""
 
 
 def read(run):
     t = run.trace
-    flops = work.step_flops(run.cell.model["model"], run.batch) * t.steps
+    flops = run.cell.family.step_flops(run.cell.model, run.batch) * t.steps
     return 100.0 * flops / t.window_s / (
         run.chips * run.peaks["bf16_flops_per_s"])

@@ -44,6 +44,11 @@ def main(argv=None) -> int:
 
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if args.trace:
+        # per-scope readings need executables compiled with their name
+        # stacks; runs without the trace keep the keys they always had
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     out = harness.run(cell, args.seed, args.seconds, bool(args.trace),
                       devices, peaks, T0)
     harness.report(out)

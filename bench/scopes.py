@@ -10,14 +10,15 @@ protobuf wire format. :func:`scope_path` keeps the named scopes of a stack
 (``blocks/pssa.qkv``), and :func:`scope_seconds` sums device time by that
 path over the traced window that ``trace.summarize`` uses, per chip.
 
-Nothing in a benchmark run calls this yet: ``trace.Summary`` would carry
-its result as one more field (see ``PERF.md``).
+``trace.summarize`` carries the result as ``Summary.scope_s``, which the
+per-layer readers of scopes read.
 
 A persistent compilation cache keyed without debug information (JAX's
 default) can hand a program an executable compiled from the same
 computation before its scopes existed; its ops then carry the old name
-stacks. Set ``jax_compilation_cache_include_metadata_in_key`` where the
-names matter.
+stacks. So ``bench/run.py`` sets
+``jax_compilation_cache_include_metadata_in_key`` in a ``--trace 1`` run,
+and only there: untraced runs keep their cache keys.
 """
 from __future__ import annotations
 

@@ -11,7 +11,9 @@ thread, and the runtime's own work (transfers, layout changes) on its
 threads. Host and device events share one clock.
 
 The traced window runs from the first traced step's ``bench.batch`` to the
-last step's ``bench.read``; only events that start inside it count.
+last step's ``bench.read``; only events that start inside it count. Device
+time by the program's named scopes comes from the same file
+(``bench/scopes.py``).
 """
 from __future__ import annotations
 
@@ -47,6 +49,7 @@ class TraceData:
     devices: dict            # plane name -> [(op, start, end, is_kernel)]
     spans: list              # [(name, start, end)] of the harness
     runtime: list            # [(name, start, end)] other host threads
+    path: str | None = None  # the .xplane.pb read, for the name stacks
 
 
 def load(path: str) -> TraceData:
@@ -71,7 +74,7 @@ def load(path: str) -> TraceData:
                     elif not line.name.startswith("python"):
                         runtime.append(item)
     spans.sort(key=lambda s: s[1])
-    return TraceData(devices, spans, runtime)
+    return TraceData(devices, spans, runtime, path)
 
 
 def load_dir(directory: str) -> TraceData:
@@ -125,6 +128,7 @@ class Summary:
     exposed_collective_s: float  # busiest chip, all steps
     ops: list                    # [(op, seconds per chip)], longest first
     gaps: list                   # [(label, seconds)], longest first
+    scope_s: dict                # scope path -> seconds per chip, all steps
 
     def breakdown(self) -> dict:
         return {"device_ops": [[n, s] for n, s in self.ops[:10]],
@@ -144,6 +148,8 @@ def _label(gap, spans, runtime) -> str:
 
 
 def summarize(data: TraceData, families: dict | None = None) -> Summary:
+    from bench import scopes
+
     families = kernel_families() if families is None else families
     starts = [s for n, s, _ in data.spans if n == "bench.batch"]
     reads = [e for n, _, e in data.spans if n == "bench.read"]
@@ -185,4 +191,6 @@ def summarize(data: TraceData, families: dict | None = None) -> Summary:
         ops=sorted(((n, s / chips) for n, s in ops_s.items()),
                    key=lambda x: -x[1]),
         gaps=[(_label(g, spans, data.runtime), (g[1] - g[0]) / 1e9)
-              for g in gaps[:10]])
+              for g in gaps[:10]],
+        scope_s=(scopes.scope_seconds(*scopes.load(data.path))
+                 if data.path else {}))

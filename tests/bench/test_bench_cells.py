@@ -94,3 +94,39 @@ def test_the_command_on_a_cpu_exits_nonzero_with_no_result():
     for line in proc.stdout.splitlines():
         with pytest.raises(json.JSONDecodeError):
             json.loads(line)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_only_a_traced_run_keys_its_cache_with_debug_information(
+        monkeypatch, trace):
+    """A ``--trace 1`` run needs executables that carry the program's
+    named scopes; an untraced one keeps the cache keys it always had, so
+    that its set-up finds what earlier runs compiled."""
+    import importlib.util
+    from types import SimpleNamespace
+
+    import jax
+
+    from bench import harness
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    seen = {}
+
+    def fake_run(*args, **kwargs):
+        seen["key"] = getattr(jax.config, flag)
+        return {"compared": {}}
+
+    monkeypatch.setattr(device, "require_tpus", lambda n: [
+        SimpleNamespace(device_kind="TPU v5 lite")])
+    monkeypatch.setattr(harness, "run", fake_run)
+    spec = importlib.util.spec_from_file_location(
+        "bench_run", ROOT / "bench" / "run.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    before = getattr(jax.config, flag)
+    try:
+        assert module.main(["--workload", CELLS[0], "--seed", "1",
+                            "--seconds", "1", "--trace", str(trace)]) == 0
+    finally:
+        jax.config.update(flag, before)
+    assert seen["key"] is bool(trace)

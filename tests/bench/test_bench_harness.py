@@ -53,5 +53,5 @@ def test_a_broken_step_is_not_correct(cell, fault):
 
 
 def test_the_bfloat16_control_is_not_correct(cell):
-    out = run(cell, jax.devices()[:1], hook=faults.control(cell.model))
+    out = run(cell, jax.devices()[:1], hook=faults.control(cell))
     assert not out["correct"], out["compared"]

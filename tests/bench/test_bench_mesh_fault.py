@@ -13,15 +13,17 @@ import json, math, sys
 sys.path[:0] = [{root!r}, {src!r}]
 import jax
 from bench import faults
-from bench.cells import BENCH, Cell, load_json
+from bench.cells import BENCH, Cell, family, load_json
 from bench.compare import NUMBERS
 from tests.bench._tiny import run, tiny
-cell = tiny(Cell("mesh", 4, load_json(BENCH / "configs/spikingformer-8-512.json"),
+model = load_json(BENCH / "configs/spikingformer-8-512.json")
+cell = tiny(Cell("mesh", 4, model,
                  load_json(BENCH / "traffic/jnp.in224.b16.data4.json"),
-                 {{k: math.inf for k in NUMBERS}}, (), ()), batch=16)
+                 {{k: math.inf for k in NUMBERS}}, (), (),
+                 family(model["family"])), batch=16)
 sound = run(cell, jax.devices()[:4], seed=3)
 broken = run(cell, jax.devices()[:4], seed=3,
-             hook=faults.no_exchange(cell.model))
+             hook=faults.no_exchange(cell))
 print(json.dumps([{{k: v["value"] for k, v in out["compared"].items()}}
                   for out in (sound, broken)]))
 """

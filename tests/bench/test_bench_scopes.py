@@ -190,3 +190,40 @@ def test_the_recorded_trace_keeps_its_summary():
     assert s.gaps[0] == ("bench.read", 0.005080026)
     assert s.gaps[-1] == ("bench.dispatch", 0.003092447)
     assert [g for g, _ in s.gaps] == ["bench.read"] * 9 + ["bench.dispatch"]
+
+
+@pytest.fixture(scope="module")
+def scoped_summary():
+    return tr.summarize(tr.load(str(DATA / "pf_tiny_scoped.xplane.pb")))
+
+
+def test_the_summary_carries_device_time_per_scope(scoped, scoped_summary):
+    """``trace.summarize`` reads the name stacks of the file it summarizes;
+    events handed to it without a file carry no scopes."""
+    assert scoped_summary.scope_s == scopes.scope_seconds(*scoped)
+    data = tr.load(str(DATA / "pf_tiny_scoped.xplane.pb"))
+    data.path = None
+    assert tr.summarize(data).scope_s == {}
+
+
+@pytest.mark.parametrize("metric, scope, ms", [
+    ("model.tokenizer_ms", "tokenizer", 0.2021095),
+    ("model.blocks_ms", "blocks", 0.1601065),
+    ("train.optimizer_ms", "optimizer", 0.0390874),
+])
+def test_scope_readers_read_the_recorded_traces(scoped_summary, metric,
+                                                scope, ms):
+    """Milliseconds per step under the scope in the recorded scoped trace
+    (10 steps); nothing in the trace recorded before the scopes."""
+    from types import SimpleNamespace
+
+    from bench.cells import metric_reader
+
+    read = metric_reader(metric)
+    value = read(SimpleNamespace(trace=scoped_summary))
+    assert value == pytest.approx(
+        1e3 * scopes.under(scoped_summary.scope_s, scope)
+        / scoped_summary.steps, rel=1e-12)
+    assert value == pytest.approx(ms, rel=1e-9)
+    unscoped = tr.summarize(tr.load(str(DATA / "pf_tiny.xplane.pb")))
+    assert read(SimpleNamespace(trace=unscoped)) is None

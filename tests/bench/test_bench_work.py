@@ -1,12 +1,14 @@
-"""The benchmark's operation and byte counts (bench/work.py)."""
+"""The benchmark's operation and byte counts: the Spikingformer family's
+(bench/families/spikingformer.py) and the roofline (bench/work.py)."""
 import pytest
 
 from bench import work
-from bench.cells import BENCH, load_json
+from bench.cells import BENCH, family, load_json
 
 PEAKS = load_json(BENCH / "peaks.json")["TPU v5 lite"]
-SF8 = load_json(BENCH / "configs" / "spikingformer-8-512.json")["model"]
-DVS = load_json(BENCH / "configs" / "spikingformer-2-256-dvs.json")["model"]
+SF = family("spikingformer")
+SF8 = load_json(BENCH / "configs" / "spikingformer-8-512.json")
+DVS = load_json(BENCH / "configs" / "spikingformer-2-256-dvs.json")
 
 #: The plan of the pallas-full arm at batch 8 on a v5e: every fused site
 #: demoted to its pipeline arm, attention's AV unpacked (N = 196).
@@ -19,8 +21,9 @@ PALLAS_FULL_B8 = {
     "attn_qk": "pallas_packed", "attn_av": "jnp"}
 
 
-def hand_count(m, b):
+def hand_count(config, b):
     """The hand count of one train step's model FLOPs."""
+    m = config["model"]
     t, d, f, n = m["time_steps"], m["d_model"], m["d_ff"], m["patch_grid"] ** 2
     convs = [(3, 64, 112), (64, 128, 56), (128, 256, 28), (256, 512, 14)]
     macs = sum(t * b * s * s * 9 * ci * co for ci, co, s in convs)
@@ -30,7 +33,7 @@ def hand_count(m, b):
 
 
 def test_step_flops_match_hand_count_for_spikingformer_8_512():
-    flops = work.step_flops(SF8, 8)
+    flops = SF.step_flops(SF8, 8)
     assert flops == hand_count(SF8, 8)
     assert flops == pytest.approx(1.14e12, rel=0.01)
     # 5.8 ms of a v5e's bf16 peak
@@ -39,21 +42,22 @@ def test_step_flops_match_hand_count_for_spikingformer_8_512():
 
 @pytest.mark.parametrize("batch", [1, 8, 16])
 def test_step_flops_scale_with_batch(batch):
-    head = 2 * 3 * batch * SF8["d_model"] * SF8["num_classes"]
-    per_image = (work.step_flops(SF8, 8) - 2 * 3 * 8 * 512 * 1000) / 8
-    assert work.step_flops(SF8, batch) == pytest.approx(
+    head = 2 * 3 * batch * SF8["model"]["d_model"] * SF8["model"][
+        "num_classes"]
+    per_image = (SF.step_flops(SF8, 8) - 2 * 3 * 8 * 512 * 1000) / 8
+    assert SF.step_flops(SF8, batch) == pytest.approx(
         per_image * batch + head)
 
 
 def test_tokenizer_stages_follow_the_configuration():
-    assert work.tokenizer_stages(SF8) == [(3, 64, 112), (64, 128, 56),
-                                          (128, 256, 28), (256, 512, 14)]
-    assert work.tokenizer_stages(DVS) == [(2, 32, 64), (32, 64, 32),
-                                          (64, 128, 16), (128, 256, 8)]
+    assert SF.tokenizer_stages(SF8["model"]) == [
+        (3, 64, 112), (64, 128, 56), (128, 256, 28), (256, 512, 14)]
+    assert SF.tokenizer_stages(DVS["model"]) == [
+        (2, 32, 64), (32, 64, 32), (64, 128, 16), (128, 256, 8)]
 
 
 def test_lif_work_counts_every_standalone_lif_site():
-    pieces = work.family_work("lif", SF8, 8, PALLAS_FULL_B8)
+    pieces = SF.kernel_work("lif", SF8, 8, PALLAS_FULL_B8)
     # 4 tokenizer stages + 8 layers x (5 PSSA + 2 SMLP) LIF scans
     assert len(pieces) == 4 + 8 * 7
     rows = 4 * 8 * 196
@@ -67,17 +71,17 @@ def test_lif_work_counts_every_standalone_lif_site():
 def test_lif_work_leaves_out_sites_a_megakernel_absorbs():
     plan = dict(PALLAS_FULL_B8, **{"pssa.qkv": "fused_epilogue",
                                    "smlp.a": "fused_epilogue"})
-    assert len(work.family_work("lif", SF8, 8, plan)) == 4 + 8 * 3
+    assert len(SF.kernel_work("lif", SF8, 8, plan)) == 4 + 8 * 3
 
 
 def test_no_family_work_on_the_jnp_arm():
     plan = {site: "jnp" for site in PALLAS_FULL_B8}
-    assert work.family_work("lif", SF8, 8, plan) == []
-    assert work.family_work("spike_mm", SF8, 8, plan) == []
+    assert SF.kernel_work("lif", SF8, 8, plan) == []
+    assert SF.kernel_work("spike_mm", SF8, 8, plan) == []
 
 
 def test_spike_mm_work_counts_packed_products_only():
-    pieces = work.family_work("spike_mm", SF8, 8, PALLAS_FULL_B8)
+    pieces = SF.kernel_work("spike_mm", SF8, 8, PALLAS_FULL_B8)
     # 3 packed conv stages x T, per layer 6 projections + T*B*h Q K^T
     assert len(pieces) == 3 * 4 + 8 * (6 + 4 * 8 * 8)
     fl = sum(f for f, _ in pieces)
