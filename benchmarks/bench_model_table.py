@@ -140,7 +140,7 @@ def time_chunk_rows() -> list[str]:
 
 def sharding_rows() -> list[str]:
     """The resolved sharding plan on a mesh over the local devices (the
-    same plan ``launch.train.build_spikingformer_state`` uses)."""
+    same plan ``launch.train.build_vision_state`` uses)."""
     import jax
 
     from repro.configs.spikingformer import get_spikingformer_config

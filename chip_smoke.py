@@ -244,12 +244,11 @@ def composed_run(cfg, batch: int, mesh, keep: int | None = None) -> dict:
     from repro.core.spiking_layers import block_apply
     from repro.core.spikingformer import tokenizer_apply
     from repro.launch.mesh import batch_axes, use_mesh
-    from repro.launch.train import build_spikingformer_state
+    from repro.launch.train import build_vision_state
     from repro.train.data import SyntheticVision, VisionDataConfig
     from repro.train.optimizer import OptimizerConfig
 
-    params, state, _, _ = build_spikingformer_state(cfg, mesh,
-                                                    OptimizerConfig())
+    params, state, _, _ = build_vision_state(cfg, mesh, OptimizerConfig())
     first = lambda tree: jax.tree.map(lambda a: a[0], tree)  # noqa: E731
     images = SyntheticVision(VisionDataConfig(
         image_size=cfg.image_size, num_classes=cfg.num_classes,

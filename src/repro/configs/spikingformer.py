@@ -1,9 +1,12 @@
-"""Named Spikingformer presets with execution-policy variants.
+"""Named Spikingformer presets with execution-policy variants, and the one
+lookup of every vision preset.
 
 Mirrors :mod:`repro.configs.registry` for the paper's own model family:
 ``get_spikingformer_config("spikingformer-8-512")`` is the paper Table III
 training target; ``"spikingformer-smoke"`` is the CPU test/bench size shared
-by the parity tests and ``benchmarks/bench_model_table.py``.
+by the parity tests and ``benchmarks/bench_model_table.py``. The same getter
+resolves the QKFormer presets of :mod:`repro.configs.qkformer`
+(:data:`VISION_PRESETS` holds both families).
 
 Execution variants are spelled ``<name>@<policy>`` with a policy preset name
 (``jnp``/``pallas``/``pallas-full``, e.g. ``spikingformer-smoke@pallas``) or
@@ -22,7 +25,9 @@ from __future__ import annotations
 
 import dataclasses
 import os
+from collections import ChainMap
 
+from repro.configs.qkformer import QKFORMER_PRESETS
 from repro.core.policy import (ExecutionPolicy, default_policy, log_fallbacks,
                                named_policy, policy_from_flags,
                                warn_deprecated_flags)
@@ -50,8 +55,9 @@ SPIKINGFORMER_PRESETS: dict[str, SpikingFormerConfig] = {
 }
 
 
-def list_spikingformer_configs() -> list[str]:
-    return sorted(SPIKINGFORMER_PRESETS)
+#: Every vision preset: the Spikingformer's and the QKFormer's (a live view
+#: of both registries).
+VISION_PRESETS = ChainMap(SPIKINGFORMER_PRESETS, QKFORMER_PRESETS)
 
 
 def get_spikingformer_config(name: str, *,
@@ -59,10 +65,10 @@ def get_spikingformer_config(name: str, *,
                              time_chunk: int | None = None,
                              backend: str | None = None,
                              spike_mm: bool | None = None,
-                             interpret: bool | None = None
-                             ) -> SpikingFormerConfig:
-    """Look up a preset, optionally rebinding the execution policy and the
-    temporal tile length (``time_chunk``, see docs/SHARDING.md).
+                             interpret: bool | None = None):
+    """Look up a vision preset (:data:`VISION_PRESETS`), optionally
+    rebinding the execution policy and, for a Spikingformer, the temporal
+    tile length (``time_chunk``, see docs/SHARDING.md).
 
     Precedence: explicit legacy flags (deprecated) > ``policy=`` kwarg >
     ``@<policy>`` name suffix > ``REPRO_BACKEND`` env var > the preset's own
@@ -72,7 +78,7 @@ def get_spikingformer_config(name: str, *,
         name, suffix = name.rsplit("@", 1)
         if policy is None:
             policy = named_policy(suffix)
-    cfg = SPIKINGFORMER_PRESETS[name]
+    cfg = VISION_PRESETS[name]
     if time_chunk is not None:
         cfg = dataclasses.replace(cfg, time_chunk=time_chunk)
     if backend is not None or spike_mm is not None or interpret is not None:

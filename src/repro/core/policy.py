@@ -609,6 +609,7 @@ def _ensure_site_tables() -> None:
     _site_tables_loading = True
     try:
         import repro.core.spikingformer  # noqa: F401  "spikingformer" table
+        import repro.core.qkformer       # noqa: F401  "qkformer" table
         import repro.models.lm           # noqa: F401  "lm" table
     finally:
         _site_tables_loading = False

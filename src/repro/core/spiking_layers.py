@@ -527,6 +527,26 @@ def _merge_heads(x: jax.Array) -> jax.Array:
     return x.transpose(0, 1, 3, 2, 4).reshape(t, b, n, h * dh)
 
 
+def _spike_attention(qs, ks, vs, n_heads: int, pol: ExecutionPolicy,
+                     qk_first: bool = True) -> jax.Array:
+    """Unscaled, softmax-free attention (Q K^T) V per head over spike
+    Q, K, V (T,B,N,D) -> (T,B,N,D), heads merged; the products dispatch at
+    the ``attn_qk``/``attn_av`` sites."""
+    qh, kh, vh = (_split_heads(a, n_heads) for a in (qs, ks, vs))
+    if qk_first:
+        attn = dispatch_kernel("attn_qk", "attn_qk",
+                               pol.resolve("attn_qk", "attn_qk"),
+                               qh, kh, pol, "attn_qk")           # spike counts
+        attn = shard(attn, *ACT_SPECS["attn.scores"])
+        out = dispatch_kernel("attn_av", "attn_av",
+                              pol.resolve("attn_av", "attn_av"),
+                              attn, vh, pol, "attn_av")
+    else:  # exact reassociation (no softmax): K^T V first — kv is dense
+        kv = jnp.einsum("tbhmd,tbhme->tbhde", kh, vh)
+        out = jnp.einsum("tbhnd,tbhde->tbhne", qh, kv)
+    return shard(_merge_heads(out), *ACT_SPECS["pssa.out"])
+
+
 def pssa_apply(params: Params, state: State, x: jax.Array, cfg: PSSAConfig,
                *, train: bool):
     """x: (T,B,N,D) real-valued features -> (T,B,N,D); residual added by caller."""
@@ -547,19 +567,8 @@ def pssa_apply(params: Params, state: State, x: jax.Array, cfg: PSSAConfig,
                                   lif_site="pssa.lif",
                                   act_spec=ACT_SPECS["pssa.qkv"])
 
-    qh, kh, vh = (_split_heads(a, cfg.n_heads) for a in (qs, ks, vs))
-    if cfg.qk_first:
-        attn = dispatch_kernel("attn_qk", "attn_qk",
-                               pol.resolve("attn_qk", "attn_qk"),
-                               qh, kh, pol, "attn_qk")           # spike counts
-        attn = shard(attn, *ACT_SPECS["attn.scores"])
-        out = dispatch_kernel("attn_av", "attn_av",
-                              pol.resolve("attn_av", "attn_av"),
-                              attn, vh, pol, "attn_av")
-    else:  # exact reassociation (no softmax): K^T V first — kv is dense
-        kv = jnp.einsum("tbhmd,tbhme->tbhde", kh, vh)
-        out = jnp.einsum("tbhnd,tbhde->tbhne", qh, kv)
-    out = shard(_merge_heads(out), *ACT_SPECS["pssa.out"]) * cfg.scale  # eq. 10
+    out = _spike_attention(qs, ks, vs, cfg.n_heads, pol,
+                           cfg.qk_first) * cfg.scale              # eq. 10
     out_s = lif_scan(out, cfg.lif_cfg, site="pssa.lif")          # SN(...)
     z, s_z = linear_bn_apply(params["z"], state["z"], out_s, train=train,
                              policy=pol, site="pssa.proj")
@@ -653,3 +662,91 @@ def block_apply(params: Params, state: State, x: jax.Array, cfg: BlockConfig,
     m, s_mlp = smlp_apply(params["smlp"], state["smlp"], x, cfg.smlp, train=train)
     x = shard(x + m, *ACT_SPECS["block.residual"])   # eq. 6 (RES)
     return x, {"pssa": s_attn, "smlp": s_mlp}
+
+
+# ---------------------------------------------------------------------------
+# Spikformer residual form (QKFormer's SSA and MLP): the branch reads the
+# residual stream itself (no leading SN) and ends in an SN, so the block adds
+# spikes: X <- X + SN(...). The residual stream then holds spike counts, not
+# {0,1} spikes, which is why the branches' first projections have a float
+# operand.
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class SSAConfig:
+    """Spikformer's SSA: Q, K, V = SN(BN(X W)); A = SN_attn(scale Q K^T V)
+    per head; out = SN(BN(A W_proj)). ``attn_lif`` is SN_attn (threshold
+    0.5 in the published code); ``policy`` rides in both LIF configs."""
+
+    d_model: int
+    n_heads: int
+    lif: LIFConfig = LIFConfig()
+    attn_lif: LIFConfig = LIFConfig()
+    scale: float = 0.125
+    policy: ExecutionPolicy = ExecutionPolicy()
+
+
+def init_projections(key, d: int, names, dtype=jnp.float32):
+    """(params, state) of one d -> d linear + BN per name."""
+    pairs = [init_linear_bn(k, d, d, dtype)
+             for k in jax.random.split(key, len(names))]
+    return ({n: p for n, (p, _) in zip(names, pairs)},
+            {n: s for n, (_, s) in zip(names, pairs)})
+
+
+def ssa_apply(params: Params, state: State, x: jax.Array, cfg: SSAConfig,
+              *, train: bool):
+    """x: (T,B,N,D) residual stream -> spikes (T,B,N,D), for the caller to
+    add. Sites ``ssa.qkv``, ``attn_qk``, ``attn_av``, ``ssa.proj``; every
+    SN at ``ssa.lif``."""
+    pol = cfg.policy
+    lif = dataclasses.replace(cfg.lif, policy=pol)
+    qkv, new = [], {}
+    for name in ("q", "k", "v"):
+        s, new[name] = linear_bn_lif_apply(
+            params[name], state[name], x, lif, train=train, policy=pol,
+            site="ssa.qkv", lif_site="ssa.lif",
+            act_spec=ACT_SPECS["pssa.qkv"])
+        qkv.append(s)
+    out = _spike_attention(*qkv, cfg.n_heads, pol) * cfg.scale
+    a = lif_scan(out, dataclasses.replace(cfg.attn_lif, policy=pol),
+                 site="ssa.lif")
+    y, new["proj"] = linear_bn_lif_apply(
+        params["proj"], state["proj"], a, lif, train=train, policy=pol,
+        site="ssa.proj", lif_site="ssa.lif")
+    return y, new
+
+
+def mlp_apply(params: Params, state: State, x: jax.Array, cfg: SMLPConfig,
+              *, train: bool):
+    """MLP(X) = SN(BN(SN(BN(X W_a)) W_b)): (T,B,N,D) residual stream ->
+    spikes (T,B,N,D). Sites ``mlp.a``, ``mlp.b``; both SNs at
+    ``mlp.lif``."""
+    pol, lif = cfg.policy, cfg.lif_cfg
+    h, s_a = linear_bn_lif_apply(params["a"], state["a"], x, lif, train=train,
+                                 policy=pol, site="mlp.a", lif_site="mlp.lif",
+                                 act_spec=ACT_SPECS["smlp.hidden"])
+    y, s_b = linear_bn_lif_apply(params["b"], state["b"], h, lif, train=train,
+                                 policy=pol, site="mlp.b", lif_site="mlp.lif")
+    return y, {"a": s_a, "b": s_b}
+
+
+# ---------------------------------------------------------------------------
+# Shared by the vision models (``core/spikingformer.py``, ``core/qkformer.py``)
+# ---------------------------------------------------------------------------
+
+def unstack_depth(tree) -> list:
+    """Split every [L, ...] leaf of a stacked block tree once (one
+    ``lax.split``, whose transpose is one concatenate) into L per-block
+    trees."""
+    leaves, treedef = jax.tree.flatten(tree)
+    n = leaves[0].shape[0]
+    parts = [[jnp.squeeze(c, 0) for c in jnp.split(leaf, n)]
+             for leaf in leaves]
+    return [treedef.unflatten([p[i] for p in parts]) for i in range(n)]
+
+
+def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
+    return jnp.mean(nll)

@@ -31,8 +31,9 @@ from repro.core.policy import (ExecutionPolicy, FUSED_EPILOGUE_IMPLS,
                                runtime_fallback, warn_deprecated_flags)
 from repro.core.spiking_layers import (ACT_SPECS, BlockConfig, _bn_pallas,
                                        _neuron_layer_site, bn_apply,
-                                       block_apply, init_block, init_bn,
-                                       init_linear, linear_apply)
+                                       block_apply, cross_entropy, init_block,
+                                       init_bn, init_linear, linear_apply,
+                                       unstack_depth)
 from repro.models.common import BATCH, MODEL, shard, spec_is_leaf
 
 Params = dict[str, Any]
@@ -285,15 +286,15 @@ class SpikingFormerConfig:
         Batch shards over the ("pod", "data") mesh axes, d_model/head
         projections over "model". Without a mesh the table shows the logical
         specs; with one, the per-leaf parameter placements actually used by
-        ``launch.train.build_spikingformer_state`` on that mesh.
+        ``launch.train.build_vision_state`` on that mesh.
         """
         lines = ["# Sharding plan (batch over ('pod','data'), "
                  "tensor-parallel over 'model')", "activation,spec"]
         for name, spec in activation_specs(self):
             lines.append(f"{name},{spec}")
         if mesh is not None:
-            from repro.launch.specs import spikingformer_structs
-            _, (specs, _) = spikingformer_structs(self, mesh)
+            from repro.launch.specs import vision_structs
+            _, (specs, _) = vision_structs(self, mesh)
             sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
             lines.append(f"param,spec  (mesh {sizes})")
             flat = jax.tree_util.tree_flatten_with_path(
@@ -303,6 +304,17 @@ class SpikingFormerConfig:
                                 for p in path)
                 lines.append(f"{name},{spec}")
         return "\n".join(lines)
+
+    # The vision train path's model interface (``launch.train``,
+    # ``launch.specs``, ``train.loop``).
+    def init(self, key):
+        return init_spikingformer(key, self)
+
+    def param_specs(self):
+        return spikingformer_param_specs(self)
+
+    def grad_step(self, params, state, images, labels):
+        return spikingformer_grad_step(params, state, images, labels, self)
 
     def param_count(self) -> int:
         d, f = self.d_model, self.d_ff
@@ -369,7 +381,7 @@ def spikingformer_param_specs(cfg: SpikingFormerConfig):
     SMLP-A column-parallel (output features over "model", with their BN
     leaves sharded alike), Z-projection and SMLP-B row-parallel (input
     features over "model", BN replicated). The vmapped block leaves carry a
-    leading L depth axis that stays unsharded (``spikingformer_scan_dims``
+    leading L depth axis that stays unsharded (``launch.specs.depth_scan_dims``
     tells ``apply_fsdp`` to skip it). Tokenizer convs and the head are
     replicated — FSDP may still shard them over "data"."""
     rep = P(None)
@@ -425,16 +437,6 @@ def lif_residual_accounting(cfg: SpikingFormerConfig, batch: int
         tiled = (2 * (t // tc) + 3 * tc) * rows * 4
     return {"elems_per_step": rows, "single_shot_bytes": single,
             "tiled_bytes": tiled}
-
-
-def spikingformer_scan_dims(specs):
-    """Per-leaf count of leading stacked dims ``apply_fsdp`` must not
-    shard: 1 for the block leaves stacked over depth, 0 elsewhere."""
-    def n_scan(path, _):
-        return 1 if any(getattr(p, "key", None) == "blocks" for p in path) \
-            else 0
-    return jax.tree_util.tree_map_with_path(
-        n_scan, specs, is_leaf=spec_is_leaf)
 
 
 # ---------------------------------------------------------------------------
@@ -693,17 +695,6 @@ def init_spikingformer(key, cfg: SpikingFormerConfig):
     return params, state
 
 
-def _unstack(tree) -> list:
-    """Split every [L, ...] leaf of a stacked block tree once (one
-    ``lax.split``, whose transpose is one concatenate) into L per-block
-    trees."""
-    leaves, treedef = jax.tree.flatten(tree)
-    n = leaves[0].shape[0]
-    parts = [[jnp.squeeze(c, 0) for c in jnp.split(leaf, n)]
-             for leaf in leaves]
-    return [treedef.unflatten([p[i] for p in parts]) for i in range(n)]
-
-
 def spikingformer_apply(params: Params, state: State, images: jax.Array,
                         cfg: SpikingFormerConfig, *, train: bool):
     """images: (T,B,H,W,C) or (B,H,W,C) (static image, repeated over T).
@@ -730,8 +721,8 @@ def spikingformer_apply(params: Params, state: State, images: jax.Array,
         layer = jax.checkpoint(layer)
     with jax.named_scope("blocks"):
         new_states = []
-        for p, s in zip(_unstack(params["blocks"]),
-                        _unstack(state["blocks"])):
+        for p, s in zip(unstack_depth(params["blocks"]),
+                        unstack_depth(state["blocks"])):
             x, s_new = layer(p, s, x)
             new_states.append(s_new)
         s_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *new_states)
@@ -740,12 +731,6 @@ def spikingformer_apply(params: Params, state: State, images: jax.Array,
         feat = shard(jnp.mean(x, axis=(0, 2)), BATCH, None)   # (B, D)
         logits = linear_apply(params["head"], feat) + params["head"]["b"]
     return logits.astype(jnp.float32), {"tokenizer": s_tok, "blocks": s_blocks}
-
-
-def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
-    return jnp.mean(nll)
 
 
 def spikingformer_loss(params, state, images, labels, cfg: SpikingFormerConfig):

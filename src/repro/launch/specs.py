@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.models.common import split_tree
+from repro.models.common import spec_is_leaf, split_tree
 
 SDS = jax.ShapeDtypeStruct
 
@@ -54,40 +54,47 @@ def param_structs(cfg: ArchConfig):
     return structs, specs_box["specs"]
 
 
-def spikingformer_structs(cfg, mesh, fsdp_min_elems: int = 1 << 20):
-    """Spikingformer (params, bn-state) structs + effective mesh specs.
+def depth_scan_dims(specs):
+    """Per-leaf count of leading stacked dims ``apply_fsdp`` must not
+    shard: 1 for the block leaves stacked over depth (under a ``blocks``
+    key), 0 elsewhere."""
+    def n_scan(path, _):
+        return 1 if any(getattr(p, "key", None) == "blocks" for p in path) \
+            else 0
+    return jax.tree_util.tree_map_with_path(
+        n_scan, specs, is_leaf=spec_is_leaf)
 
-    The single source of the vision sharding plan: logical specs from
-    ``spikingformer_param_specs`` are sanitized against the mesh and FSDP'd
-    over "data" (the stacked block leaves keep their leading L depth axis
-    unsharded via ``spikingformer_scan_dims``). Used by
-    ``launch.train.build_spikingformer_state``, the vision dry-run cell and
+
+def vision_structs(cfg, mesh, fsdp_min_elems: int = 1 << 20):
+    """(params, bn-state) structs + effective mesh specs of a vision model
+    (``cfg.init`` and ``cfg.param_specs``: the Spikingformer or QKFormer).
+
+    The single source of the vision sharding plan: the model's logical
+    specs are sanitized against the mesh and FSDP'd over "data" (leaves
+    stacked over depth, under a ``blocks`` key, keep their leading axis
+    unsharded via :func:`depth_scan_dims`). Used by
+    ``launch.train.build_vision_state``, the vision dry-run cell and
     ``SpikingFormerConfig.describe_execution(mesh)``.
     """
-    from repro.core.spikingformer import (init_spikingformer,
-                                          spikingformer_param_specs,
-                                          spikingformer_scan_dims)
     from repro.launch.mesh import apply_fsdp, sanitize_specs
 
-    p_struct, s_struct = jax.eval_shape(
-        lambda k: init_spikingformer(k, cfg), jax.random.PRNGKey(0))
-    p_specs, s_specs = spikingformer_param_specs(cfg)
+    p_struct, s_struct = jax.eval_shape(cfg.init, jax.random.PRNGKey(0))
+    p_specs, s_specs = cfg.param_specs()
     p_specs = sanitize_specs(p_specs, p_struct, mesh)
     p_specs = apply_fsdp(p_specs, p_struct, mesh, min_elems=fsdp_min_elems,
-                         scan_dims=spikingformer_scan_dims(p_specs))
+                         scan_dims=depth_scan_dims(p_specs))
     s_specs = sanitize_specs(s_specs, s_struct, mesh)
     return (p_struct, s_struct), (p_specs, s_specs)
 
 
 def _vision_input_specs(cfg, sh: ShapeSpec, mesh, ba):
-    """(fn, args_structs, args_specs) for a Spikingformer train cell."""
+    """(fn, args_structs, args_specs) for a vision train cell."""
     from repro.train.loop import make_train_step
     from repro.train.optimizer import OptimizerConfig
     if sh.kind != "train":
         raise ValueError(
             f"vision cells are train-only (shape kind {sh.kind!r})")
-    (p_struct, s_struct), (p_specs, s_specs) = spikingformer_structs(cfg,
-                                                                     mesh)
+    (p_struct, s_struct), (p_specs, s_specs) = vision_structs(cfg, mesh)
     o_struct, o_specs = opt_structs(p_struct, p_specs)
     b = sh.batch
     images = SDS((b, cfg.image_size, cfg.image_size, cfg.in_channels),

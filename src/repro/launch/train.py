@@ -12,6 +12,8 @@ factory.
       --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
   PYTHONPATH=src python -m repro.launch.train --arch spikingformer-tiny \
       --steps 100 --batch 16 --policy pallas --time-chunk 2
+  PYTHONPATH=src python -m repro.launch.train --arch qkformer-smoke \
+      --steps 10 --batch 4
 """
 from __future__ import annotations
 
@@ -66,24 +68,30 @@ def build_state(cfg, mesh, opt_cfg, seed: int = 0):
     return params, opt_state, specs
 
 
-def build_spikingformer_state(cfg, mesh, opt_cfg, seed: int = 0,
-                              fsdp_min_elems: int = 1 << 20):
-    """Init Spikingformer params + BN state + opt state into their mesh
-    shardings (the vision twin of :func:`build_state`)."""
-    from repro.core.spikingformer import init_spikingformer
-    from repro.launch.specs import spikingformer_structs
+def build_vision_state(cfg, mesh, opt_cfg, seed: int = 0,
+                       fsdp_min_elems: int = 1 << 20):
+    """Init a vision model's params + BN state + opt state into their mesh
+    shardings (the vision twin of :func:`build_state`); ``cfg`` is a
+    Spikingformer or QKFormer config."""
+    from repro.launch.specs import vision_structs
 
-    _, (p_specs, s_specs) = spikingformer_structs(cfg, mesh, fsdp_min_elems)
+    _, (p_specs, s_specs) = vision_structs(cfg, mesh, fsdp_min_elems)
     to_shardings = lambda specs: jax.tree.map(  # noqa: E731
         lambda s: NamedSharding(mesh, s if s is not None else P()),
         specs, is_leaf=spec_is_leaf)
     with use_mesh(mesh):
         params, state = jax.jit(
-            lambda k: init_spikingformer(k, cfg),
+            cfg.init,
             out_shardings=(to_shardings(p_specs), to_shardings(s_specs)))(
             jax.random.PRNGKey(seed))
         opt_state = init_opt_state(params)
     return params, state, opt_state, (p_specs, s_specs)
+
+
+#: The older name, called only by the benchmark's Spikingformer family
+#: (``bench/families/spikingformer.py``); it goes when that file next
+#: changes.
+build_spikingformer_state = build_vision_state
 
 
 def _drive(mesh, *, start: int, steps: int, step_once, save, log_line,
@@ -163,18 +171,18 @@ def train_vision(cfg, *, steps: int, global_batch: int,
                  ckpt_dir: str | None, mesh=None, microbatches: int = 1,
                  log_every: int = 10, ckpt_every: int = 100, seed: int = 0,
                  lr: float = 2e-3):
-    """Mesh-sharded Spikingformer BPTT training (the vision twin of
-    :func:`train`): batch shards over ("pod", "data"), projections/heads
-    over "model", FSDP'd weights, synthetic quadrant-blob data through
-    ``place_batch``, checkpointing (params + BN state + optimizer) with
-    elastic restore."""
+    """Mesh-sharded BPTT training of a vision model, the Spikingformer or
+    QKFormer (the vision twin of :func:`train`): batch shards over ("pod",
+    "data"), projections/heads over "model", FSDP'd weights, synthetic
+    quadrant-blob data through ``place_batch``, checkpointing (params + BN
+    state + optimizer) with elastic restore."""
     mesh = mesh or make_test_mesh(jax.device_count(), 1)
     # The plan at this batch: capacity demotions are decided (and logged)
     # here, from shape, before anything compiles.
     log_fallbacks(cfg.execution_plan(global_batch))
     opt_cfg = OptimizerConfig(lr=lr, total_steps=steps, weight_decay=0.01,
                               warmup_steps=max(steps // 20, 5))
-    params, state, opt_state, (p_specs, s_specs) = build_spikingformer_state(
+    params, state, opt_state, (p_specs, s_specs) = build_vision_state(
         cfg, mesh, opt_cfg, seed)
     from repro.train.optimizer import init_opt_specs
     specs = {"params": p_specs, "state": s_specs,
@@ -290,35 +298,39 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int = 128,
 
 
 def _resolve_config(args):
-    """LM/audio registry first; spikingformer preset names (optionally with
-    an ``@<policy>`` suffix) route to the vision path. Flags that only
-    exist for the other family are rejected, never silently dropped."""
+    """LM/audio registry first; Spikingformer and QKFormer preset names
+    (optionally with an ``@<policy>`` suffix) route to the vision path.
+    Flags that only exist for the other family are rejected, never
+    silently dropped."""
     try:
         cfg = get_config(args.arch)
     except KeyError:
         from repro.configs.registry import list_configs
-        from repro.configs.spikingformer import (get_spikingformer_config,
-                                                 list_spikingformer_configs)
+        from repro.configs.spikingformer import (VISION_PRESETS,
+                                                 get_spikingformer_config)
         from repro.core.policy import named_policy
         if args.reduced:
             raise SystemExit("--reduced applies to LM/audio archs only; "
-                             "pick a smaller spikingformer preset instead")
+                             "pick a smaller vision preset instead")
         if args.data_vocab is not None or args.seq is not None:
             raise SystemExit("--data-vocab/--seq apply to LM/audio archs "
                              "only (the vision data stream is sized by the "
                              "preset's image_size/num_classes)")
-        try:
-            return get_spikingformer_config(
-                args.arch,
-                policy=named_policy(args.policy) if args.policy else None,
-                time_chunk=args.time_chunk)
-        except KeyError:
+        preset = VISION_PRESETS.get(args.arch.split("@")[0])
+        if preset is None:
             raise SystemExit(
                 f"unknown --arch {args.arch!r}; LM/audio: {list_configs()}; "
-                f"vision: {list_spikingformer_configs()}") from None
+                f"vision: {sorted(VISION_PRESETS)}")
+        if args.time_chunk and not hasattr(preset, "time_chunk"):
+            raise SystemExit("--time-chunk applies to Spikingformer archs "
+                             f"only, not {args.arch!r}")
+        return get_spikingformer_config(
+            args.arch,
+            policy=named_policy(args.policy) if args.policy else None,
+            time_chunk=args.time_chunk)
     if args.policy or args.time_chunk:
-        raise SystemExit("--policy/--time-chunk apply to spikingformer "
-                         f"archs only, not {args.arch!r}")
+        raise SystemExit("--policy/--time-chunk apply to vision archs "
+                         f"only, not {args.arch!r}")
     if args.reduced:
         cfg = reduced(cfg)
     return cfg
@@ -336,9 +348,9 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--data-vocab", type=int, default=None)
     ap.add_argument("--policy", default=None,
-                    help="execution policy preset for spikingformer archs")
+                    help="execution policy preset for vision archs")
     ap.add_argument("--time-chunk", type=int, default=None,
-                    help="temporal tile length for spikingformer BPTT")
+                    help="temporal tile length for vision BPTT")
     ap.add_argument("--chaos-schedule", default=None,
                     help="fault-injection schedule (JSON file or inline "
                          "JSON; also honored via $CHAOS_SCHEDULE). See "

@@ -3,12 +3,13 @@ per-microbatch reduce-scatter overlaps the next microbatch's compute under
 XLA's latency-hiding scheduler), AdamW apply, metrics.
 
 ``make_train_step(cfg, ...)`` is the single train-step factory for every
-family — LM/audio (``ArchConfig``) and the Spikingformer vision path
-(``SpikingFormerConfig``) — and returns a pure function suitable both for
-jit execution and for ``.lower().compile()`` in the multi-pod dry-run.
-Mesh awareness lives in the model code (``shard`` constraints that no-op
-without an ambient mesh) plus the optional ``mesh=`` kwarg, which adds the
-input-batch constraints; callers run the step under ``jax.set_mesh``.
+family — LM/audio (``ArchConfig``) and the vision path
+(``SpikingFormerConfig``, ``QKFormerConfig``) — and returns a pure function
+suitable both for jit execution and for ``.lower().compile()`` in the
+multi-pod dry-run. Mesh awareness lives in the model code (``shard``
+constraints that no-op without an ambient mesh) plus the optional
+``mesh=`` kwarg, which adds the input-batch constraints; callers run the
+step under ``jax.set_mesh``.
 """
 from __future__ import annotations
 
@@ -57,7 +58,7 @@ def make_train_step(cfg: Any, opt_cfg: OptimizerConfig,
       metrics)``. ``batch`` leaves have leading dim (global_batch, ...);
       with microbatches > 1 they are split (microbatches, global_batch //
       microbatches, ...) and accumulated.
-    * Spikingformer vision (``cfg.family == "vision"``): returns
+    * vision (``cfg.family == "vision"``): returns
       ``train_step(params, state, opt_state, images, labels) -> (params,
       state, opt_state, metrics)`` where ``state`` carries BN running
       statistics.
@@ -67,7 +68,7 @@ def make_train_step(cfg: Any, opt_cfg: OptimizerConfig,
     pre-placed by ``place_batch``); activation/parameter placement is the
     model's ``shard`` constraints plus the shardings params were
     initialized into (see ``launch.train.build_state`` /
-    ``build_spikingformer_state``).
+    ``build_vision_state``).
 
     ``guard_nonfinite`` (default on) adds in-jit non-finite detection: when
     the loss or any gradient leaf is NaN/Inf, the parameter and optimizer
@@ -120,9 +121,10 @@ def make_train_step(cfg: Any, opt_cfg: OptimizerConfig,
 def _make_vision_train_step(cfg, opt_cfg: OptimizerConfig,
                             microbatches: int, mesh,
                             guard_nonfinite: bool = True) -> Callable:
-    """Fused BPTT + AdamW step for the Spikingformer vision path.
+    """Fused BPTT + AdamW step for the vision path.
 
-    ``cfg`` is a :class:`repro.core.spikingformer.SpikingFormerConfig`; its
+    ``cfg`` is a vision model's config (the Spikingformer's or the
+    QKFormer's), whose ``grad_step`` is the model's BPTT step; its
     ``policy`` field (an :class:`repro.core.policy.ExecutionPolicy`) selects
     the execution path per site, so the same train step runs the reference
     jnp scan on CPU and the fused SOMA/GRAD (+ packed spike-matmul /
@@ -132,8 +134,6 @@ def _make_vision_train_step(cfg, opt_cfg: OptimizerConfig,
     (callers jit it; :func:`make_spikingformer_train_step` does so for the
     single-device path) where ``state`` carries BN running statistics.
     """
-    from repro.core.spikingformer import spikingformer_grad_step
-
     if microbatches != 1:
         # Accumulating grads across microbatches would also have to merge
         # BN batch statistics; refuse rather than silently change the math.
@@ -156,8 +156,8 @@ def _make_vision_train_step(cfg, opt_cfg: OptimizerConfig,
                          *([None] * (images.ndim - len(lead) - 1)))
             images = jax.lax.with_sharding_constraint(images, img_spec)
             labels = jax.lax.with_sharding_constraint(labels, P(batch_axes_))
-        grads, new_state, metrics = spikingformer_grad_step(
-            params, state, images, labels, cfg)
+        grads, new_state, metrics = cfg.grad_step(params, state, images,
+                                                  labels)
         with jax.named_scope("optimizer"):
             new_params, new_opt, opt_metrics = adamw_update(
                 params, grads, opt_state, opt_cfg)

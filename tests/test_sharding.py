@@ -96,13 +96,13 @@ def test_time_chunk_composes_with_mesh(mesh, model, batch):
 
 
 def test_build_state_shards_params_and_moments(mesh):
-    """build_spikingformer_state: model-parallel leaves on "model", FSDP
+    """build_vision_state: model-parallel leaves on "model", FSDP
     leaves on "data" (stacked block leaves keep the L axis unsharded), and
     the Adam moments shard exactly like the params."""
-    from repro.launch.train import build_spikingformer_state
+    from repro.launch.train import build_vision_state
     from repro.train.optimizer import OptimizerConfig
 
-    params, state, opt, (p_specs, _) = build_spikingformer_state(
+    params, state, opt, (p_specs, _) = build_vision_state(
         CFG, mesh, OptimizerConfig(), fsdp_min_elems=1024)
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
     n_model = sum(1 for _, l in flat if "model" in str(l.sharding.spec))
@@ -138,11 +138,11 @@ def test_checkpoint_roundtrip_sharded_mesh(mesh, tmp_path):
     """Spikingformer params + opt state saved under the (4, 2) mesh restore
     onto a *different* mesh (host-count-agnostic: the saved logical specs
     re-resolve against the new mesh), values and shardings preserved."""
-    from repro.launch.train import build_spikingformer_state
+    from repro.launch.train import build_vision_state
     from repro.train import checkpoint as ckpt
     from repro.train.optimizer import OptimizerConfig, init_opt_specs
 
-    params, state, opt, (p_specs, s_specs) = build_spikingformer_state(
+    params, state, opt, (p_specs, s_specs) = build_vision_state(
         CFG, mesh, OptimizerConfig(), fsdp_min_elems=1024)
     tree = {"params": params, "state": state, "opt": opt}
     specs = {"params": p_specs, "state": s_specs,
